@@ -17,9 +17,9 @@ f64 host prequantizer, both within bound.)
 GB/s is raw input bytes over wall time (the write-path twin of the
 decoder tables' quant-code GB/s).  A ``compress_tree`` row times the
 multi-tensor entry point the checkpoint/KV consumers actually call.  As
-everywhere in this harness, timings are CPU wall-clock of the jit'd
-reference pipelines; the Pallas bit-pack kernel runs the same phases and
-is validated in interpret mode by tests/.
+everywhere in this harness, timings are host wall-clock of the jit'd
+reference pipelines on JAX's default backend; the Pallas bit-pack kernel
+runs the same phases and is held bit-exact to them by tests/.
 """
 
 from __future__ import annotations

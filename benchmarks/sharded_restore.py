@@ -1,22 +1,22 @@
 """Sharded restore throughput vs device count (docs/distributed.md).
 
-A fixed 8-shard mesh-sharded checkpoint is written once; restore is then
-timed at 1/2/4/8 "hosts" on a forced-8-device subprocess
-(``launch.mesh.forced_host_devices_env``, single-threaded devices so
-scaling reflects device count, not the intra-op thread pool).
+A fixed mesh-sharded checkpoint (one shard per device) is written once;
+restore is then timed at 1/2/4/8 "hosts".  On a TPU the suite runs in the
+harness's own process on ``jax.devices()`` (a chip belongs to one process,
+so a child could not reach it).  On the CPU it runs in a forced-8-device
+subprocess (``launch.mesh.forced_host_devices_env``, single-threaded
+devices so scaling reflects device count, not the intra-op thread pool).
 
 What is timed is the per-host critical path
 (``ShardedRestorer.decode_shards``): with H hosts each decodes its own
-8/H shard archives concurrently and places the tiles on its devices, so
-the restore wall-clock is one host's share and the *aggregate* decode
+shard archives concurrently and places the tiles on its devices, so the
+restore wall-clock is one host's share and the *aggregate* decode
 throughput scales with H.  Shares are equal-sized (equal tile grids), so
 host 0's share is the critical path.  A full ``restore()`` into target
-``NamedSharding``s on the 8-device mesh is also timed (``full_mesh`` row)
+``NamedSharding``s on the whole mesh is also timed (``full_mesh`` row)
 and its sharding landing asserted.
 
-Run via ``benchmarks.run --only sharded`` (suite key ``"sharded"``); the
-1->8 device rows are recorded in ``BENCH_baseline.json`` and join the CI
-perf gate.
+Run via ``benchmarks.run --only sharded`` (suite key ``"sharded"``).
 """
 
 from __future__ import annotations
@@ -31,6 +31,9 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def run(n: int = 1 << 19, quick: bool = False):
+    import jax
+    if jax.default_backend() == "tpu":
+        return _worker(n, quick, jax.devices())
     from repro.launch.mesh import forced_host_devices_env
     env = forced_host_devices_env(N_DEVICES, single_threaded=True,
                                   base_env=os.environ)
@@ -51,7 +54,7 @@ def run(n: int = 1 << 19, quick: bool = False):
 
 
 # ---------------------------------------------------------------------------
-# worker (runs under forced host devices; jax imported only here)
+# worker (in-process on a TPU, under forced host devices on the CPU)
 # ---------------------------------------------------------------------------
 
 
@@ -65,10 +68,9 @@ def _timeit(fn, repeats: int = 3) -> float:
     return sorted(ts)[len(ts) // 2]
 
 
-def _worker(n: int, quick: bool) -> list:
+def _worker(n: int, quick: bool, devs: list) -> list:
     import tempfile
 
-    import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from benchmarks import datasets as DS
@@ -76,41 +78,40 @@ def _worker(n: int, quick: bool) -> list:
     from repro.distributed import ShardedRestorer, ShardedWriter
     from repro.launch.mesh import make_host_mesh
 
-    devs = jax.devices()
-    assert len(devs) == N_DEVICES, f"expected {N_DEVICES} forced devices"
+    n_dev = len(devs)
     names = ["HACC", "CESM"] if quick else ["HACC", "CESM", "Nyx", "EXAALT"]
     codec = Codec(CodecConfig(eb=1e-3, mode="rel"))
     rows = []
     with tempfile.TemporaryDirectory() as d:
         ckpt = os.path.join(d, "sharded")
         total_bytes = 0
-        with ShardedWriter(ckpt, {"data": N_DEVICES}, codec=codec,
-                           n_shards=N_DEVICES) as sw:
+        with ShardedWriter(ckpt, {"data": n_dev}, codec=codec,
+                           n_shards=n_dev) as sw:
             for name in names:
                 x, _ = DS.make_dataset(name, n)
-                x = x.reshape(N_DEVICES * 64, -1)    # tile rows evenly
+                x = x.reshape(n_dev * 64, -1)        # tile rows evenly
                 total_bytes += x.nbytes
                 sw.add(f"params.{name}", x, P("data"))
         restorer = ShardedRestorer(ckpt, codec=codec)
 
-        # Full restore into target shardings on the whole 8-device mesh;
-        # warms the plan cache for every timed run below.
-        mesh = make_host_mesh(N_DEVICES, 1)
+        # Full restore into target shardings on the whole mesh; warms the
+        # plan cache for every timed run below.
+        mesh = make_host_mesh(n_dev, 1, devices=devs)
         targets = {e: NamedSharding(mesh, P("data"))
                    for e in restorer.names}
         out = restorer.restore(targets)
         for e, arr in out.items():
-            assert len(arr.addressable_shards) == N_DEVICES, e
+            assert len(arr.addressable_shards) == n_dev, e
         t_full = _timeit(lambda: restorer.restore(targets))
         rows.append(["sharded/restore/full_mesh", t_full * 1e6,
                      f"GBps={total_bytes / t_full / 1e9:.3f};"
-                     f"shards={N_DEVICES};entries={len(names)}"])
+                     f"shards={n_dev};entries={len(names)}"])
 
         # Per-host critical path at 1/2/4/8 hosts: host 0 decodes its
-        # 8/H-shard share onto its devices; aggregate = total bytes over
+        # n_dev/H-shard share onto its devices; aggregate = total bytes over
         # that wall-clock (all hosts run concurrently, shares are equal).
-        for hosts in (1, 2, 4, 8):
-            share = N_DEVICES // hosts
+        for hosts in (h for h in (1, 2, 4, 8) if n_dev % h == 0):
+            share = n_dev // hosts
             local = devs[:share]
             t = _timeit(lambda: restorer.decode_shards(range(share),
                                                        devices=local))
@@ -132,7 +133,8 @@ if __name__ == "__main__":
     ap.add_argument("--quick", action="store_true")
     a = ap.parse_args()
     if a.worker:
-        print(json.dumps(_worker(a.n, a.quick)))
+        import jax
+        print(json.dumps(_worker(a.n, a.quick, jax.devices())))
     else:
         for r in run(a.n, a.quick):
             print(r)

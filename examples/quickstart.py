@@ -33,8 +33,8 @@ def main():
               f"(bound {c.eb_effective:.3e}) "
               f"{'OK' if err <= c.eb_effective else 'VIOLATION'}")
 
-    # kernel path (Pallas, interpret mode on CPU) with the online tuner's
-    # per-CR-class tiles: one config, no flag soup.
+    # kernel path (Pallas: compiled on a TPU, interpreted on the CPU) with
+    # the online tuner's per-CR-class tiles: one config, no flag soup.
     tuned = Codec(CodecConfig(backend="pallas", strategy="tuned"))
     xh = np.asarray(tuned.decompress(c))
     print(f"decompress[pallas-tuned]: max err {np.abs(xh - x).max():.3e}")

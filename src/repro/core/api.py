@@ -6,7 +6,7 @@ on the decoder side) to a backend handle and a digest-keyed ``PlanCache``:
 
     from repro.core.api import Codec, CodecConfig
 
-    codec = Codec(CodecConfig(eb=1e-4, backend="pallas", strategy="tuned"))
+    codec = Codec(CodecConfig(eb=1e-4, strategy="tuned"))
     c = codec.compress(x)
     xhat = codec.decompress(c)                 # phase 1-3 plan cached
     tree = codec.compress_tree(params)         # pytree of Compressed leaves

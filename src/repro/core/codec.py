@@ -22,7 +22,7 @@ default Codec; the legacy ``use_tiles`` / ``use_kernels`` / ``tuned`` flag
 triple is gone from every signature and raises a ``TypeError`` pointing at
 ``CodecConfig``.
 
-    codec = Codec(CodecConfig(eb=1e-4, backend="pallas", strategy="tuned"))
+    codec = Codec(CodecConfig(eb=1e-4, strategy="tuned"))
     c = codec.compress(x)
     xhat = codec.decompress(c)                  # plan cached by digest
     shards = codec.compress_tree({"w": w, "b": b})
@@ -68,7 +68,7 @@ class CodecConfig:
       encode_backend   a ``pipeline.available_encode_backends()`` name:
                        "ref" is the host write path (f64 prequantization +
                        numpy histogram + searchsorted bit-pack); "jnp" /
-                       "pallas" / "pallas-compiled" run quantize ->
+                       "pallas" run quantize ->
                        outlier gather -> histogram -> bit-pack emit on
                        device, transferring only the 2*radius-entry
                        histogram to host for codebook construction.  The
@@ -88,13 +88,12 @@ class CodecConfig:
       fused            decode→dequantize→reconstruct in ONE dispatch: phase
                        4 emits reconstructed values directly, never writing
                        the uint16 quant-code array to HBM.  Bit-exact with
-                       the two-pass path.  Serves 1-D/2-D/3-D tensors
+                       the two-pass path.  Serves tensors of any rank
                        (unit axes squeezed) in float32 / bfloat16 / float16
                        (``compressor.FUSED_DTYPES``; low-precision outputs
                        compute in f32 with one final cast).  Decodes it
-                       cannot serve (>3-D tensors, other dtypes, rows over
-                       ``compressor.FUSED_MAX_COLS``, 3-D planes over
-                       ``compressor.FUSED_MAX_PLANE``, the "tuned"
+                       cannot serve (other dtypes, rows over
+                       ``compressor.FUSED_MAX_COLS``, the "tuned"
                        strategy, "naive_ref", or a backend without fused
                        ops) automatically fall back to two-pass and count
                        ``stats["fused_fallbacks"]`` once per tensor.

@@ -29,10 +29,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.huffman.bits import SUBSEQ_BITS, peek
-from repro.core.huffman.encode import EncodedStream
+from repro.core.huffman.encode import EncodedStream, prefix_sum
 
 # Worst-case codewords per 128-bit subsequence (min codeword length 1).
 MAX_SYMS_PER_SUBSEQ = SUBSEQ_BITS
+# Decoder lanes per batch of ``decode_write_tiles``.
+TILE_BATCH_LANES = 1 << 19
 
 
 # ---------------------------------------------------------------------------
@@ -214,10 +216,11 @@ def selfsync_inter(units, dec_sym, dec_len, start_bits, total_bits,
     return start, rounds
 
 
+@jax.jit
 def output_offsets(counts):
     """Phase 3: exclusive prefix sum of per-subsequence symbol counts."""
     c = counts.astype(jnp.int32)
-    return jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(c)])
+    return jnp.concatenate([jnp.zeros(1, jnp.int32), prefix_sum(c)])
 
 
 # ---------------------------------------------------------------------------
@@ -299,7 +302,11 @@ def decode_write_tiles(units, dec_sym, dec_len, start_bits, end_bits, offsets,
             jnp.where(valid, padded, 0), mode="drop")
         return tile
 
-    tiles = jax.vmap(decode_tile)(jnp.arange(n_tiles), s0)
+    # Batches of tiles keep the (tiles, ss_max, MAX_SYMS) intermediates
+    # bounded, so a field of 10^8 symbols decodes inside one accelerator's
+    # memory.
+    tiles = jax.lax.map(lambda a: decode_tile(*a), (jnp.arange(n_tiles), s0),
+                        batch_size=max(1, TILE_BATCH_LANES // ss_max))
     return tiles.reshape(-1)[:n_out]
 
 

@@ -112,6 +112,15 @@ def sort_by_class(classes: jnp.ndarray):
     return keys, vals
 
 
+@functools.partial(jax.jit, static_argnames=("subseqs_per_seq", "t_high"))
+def _classify_sequences(seq_counts, subseqs_per_seq: int, t_high: int):
+    """CLASSIFY, HISTOGRAM and SORT of Alg. 2 in one program: returns
+    ``(classes, class histogram, sequence ids sorted by class)``."""
+    classes = classify(sequence_ratios(seq_counts, subseqs_per_seq), t_high)
+    return (classes, class_histogram(classes, t_high),
+            sort_by_class(classes)[1])
+
+
 def tile_for_class(c: int, t_high: int = T_HIGH_DEFAULT) -> int:
     """Buffer (tile) size for a class: 1024 symbols per CR unit, as in the
     paper ("sequences in the (3,4] group ... buffer of length 4096"), with
@@ -144,10 +153,8 @@ def make_plan(stream, seq_counts, subseqs_per_seq: int,
     encoded stream can pass it alongside its metadata unchanged.
     """
     del stream
-    ratios = sequence_ratios(jnp.asarray(seq_counts), subseqs_per_seq)
-    classes = classify(ratios, t_high)
-    hist = class_histogram(classes, t_high)
-    keys, order = sort_by_class(classes)
+    classes, hist, order = _classify_sequences(
+        jnp.asarray(seq_counts), subseqs_per_seq, t_high)
     class_start = np.zeros(t_high + 3, np.int32)
     class_start[1:] = np.cumsum(np.asarray(hist))
     return ClassPlan(
@@ -179,9 +186,9 @@ class OutputTransform:
 
     ``shape`` selects the reconstruction geometry: ``None`` (or any shape
     with at most one non-unit axis) runs the 1-D chained-carry epilogue,
-    2-D/3-D shapes run the row/plane-carry epilogue (cumsum along every
-    axis).  ``out_dtype`` is the reconstruction output dtype; the epilogue
-    computes in f32 and casts once at the end, matching
+    N-D shapes run the row-carry epilogue with one carry per outer axis
+    (cumsum along every axis).  ``out_dtype`` is the reconstruction output
+    dtype; the epilogue computes in f32 and casts once at the end, matching
     ``lorenzo.dequantize`` bit-for-bit for bf16/f16.  Both default to the
     historical 1-D float32 behavior.
     """
@@ -375,50 +382,38 @@ def _make_ref_backend() -> DecodeBackend:
                          fused_padded_fn=fused_padded)
 
 
-def _make_pallas_backend(interpret: bool = True) -> DecodeBackend:
-    """Kernel backend.  ``interpret=True`` runs the Pallas interpreter (the
-    CPU-safe default of this container); ``interpret=False`` compiles the
-    kernels for the accelerator (registered as "pallas-compiled")."""
-    import functools
-
+def _make_pallas_backend() -> DecodeBackend:
+    """Kernel backend: the Pallas kernels of ``repro.kernels``, compiled on
+    a TPU and run by the Pallas interpreter elsewhere
+    (``kernels.common.use_interpreter`` decides, nothing else)."""
     from repro.kernels import ops  # lazy: keeps core jnp-only by default
 
     def count(units, ds, dl, start_abs, end_abs, total_bits, max_len):
         counts, _ = ops.subseq_counts(units, ds, dl, start_abs, end_abs,
-                                      total_bits, max_len,
-                                      interpret=interpret)
+                                      total_bits, max_len)
         return counts
 
     def sync(units, ds, dl, total_bits, n_subseq, sps, max_len,
              early_exit=True):
         start, counts, _ = ops.selfsync_sync(units, ds, dl, total_bits,
                                              n_subseq, sps, max_len,
-                                             early_exit=early_exit,
-                                             interpret=interpret)
+                                             early_exit=early_exit)
         return start, counts
 
     def padded(units, ds, dl, start_abs, end_abs, total_bits, max_len, n_out):
         out, _ = ops.decode_padded_compact(units, ds, dl, start_abs, end_abs,
-                                           total_bits, max_len, n_out,
-                                           interpret=interpret)
+                                           total_bits, max_len, n_out)
         return out
 
-    name = "pallas" if interpret else "pallas-compiled"
     return DecodeBackend(
-        name=name, count_fn=count, sync_fn=sync,
-        tiles_fn=functools.partial(ops.decode_write_tiles,
-                                   interpret=interpret),
-        padded_fn=padded,
-        fused_tiles_fn=functools.partial(ops.decode_write_tiles_fused,
-                                         interpret=interpret),
-        fused_padded_fn=functools.partial(ops.decode_padded_fused,
-                                          interpret=interpret))
+        name="pallas", count_fn=count, sync_fn=sync,
+        tiles_fn=ops.decode_write_tiles, padded_fn=padded,
+        fused_tiles_fn=ops.decode_write_tiles_fused,
+        fused_padded_fn=ops.decode_padded_fused)
 
 
 register_backend("ref", _make_ref_backend)
 register_backend("pallas", _make_pallas_backend)
-register_backend("pallas-compiled",
-                 lambda: _make_pallas_backend(interpret=False))
 
 
 # ---------------------------------------------------------------------------
@@ -575,34 +570,28 @@ def _make_jnp_encode_backend() -> EncodeBackend:
                          hist_fn=_sorted_histogram, pack_fn=_gather_pack)
 
 
-def _make_pallas_encode_backend(interpret: bool = True) -> EncodeBackend:
+def _make_pallas_encode_backend() -> EncodeBackend:
     """Kernel backend: Lorenzo quantize + histogram + bit-pack kernels
-    (``interpret=True`` is the CPU-safe default of this container)."""
+    (compiled on a TPU, interpreted elsewhere)."""
     from repro.kernels import ops  # lazy: keeps core jnp-only by default
 
     def quantize(x, abs_eb, radius):
         x = jnp.asarray(x)
         if x.ndim == 1:
-            return ops.lorenzo_quantize(x, abs_eb, radius=radius,
-                                        interpret=interpret)
+            return ops.lorenzo_quantize(x, abs_eb, radius=radius)
         return _jnp_quantize(x, abs_eb, radius)
 
     def pack(symbols, enc_code, enc_len, total_bits, sps, min_len):
         return ops.encode_bitpack(symbols, enc_code, enc_len, total_bits,
-                                  sps, min_len=min_len, interpret=interpret)
+                                  sps, min_len=min_len)
 
-    name = "pallas" if interpret else "pallas-compiled"
-    return EncodeBackend(
-        name=name, device=True, quantize_fn=quantize,
-        hist_fn=functools.partial(ops.histogram, interpret=interpret),
-        pack_fn=pack)
+    return EncodeBackend(name="pallas", device=True, quantize_fn=quantize,
+                         hist_fn=ops.histogram, pack_fn=pack)
 
 
 register_encode_backend("ref", _make_ref_encode_backend)
 register_encode_backend("jnp", _make_jnp_encode_backend)
 register_encode_backend("pallas", _make_pallas_encode_backend)
-register_encode_backend("pallas-compiled",
-                        lambda: _make_pallas_encode_backend(interpret=False))
 
 
 @dataclasses.dataclass
@@ -695,6 +684,20 @@ class DecoderPlan:
     t_high: int
 
 
+@functools.partial(jax.jit, static_argnames=("n_subseq",))
+def _window_bounds(n_subseq: int):
+    """First bit of every 128-bit window, and of the window after it."""
+    b = jnp.arange(n_subseq, dtype=jnp.int32) * SUBSEQ_BITS
+    return b, b + SUBSEQ_BITS
+
+
+@jax.jit
+def _gap_starts(boundaries, gaps):
+    """Sync start of every window: its boundary plus the stored gap,
+    clamped to the window."""
+    return boundaries + jnp.minimum(gaps.astype(jnp.int32), SUBSEQ_BITS)
+
+
 def build_plan(stream: EncodedStream, codebook, method: str = "gap",
                backend: "str | DecodeBackend" = "ref",
                t_high: int = T_HIGH_DEFAULT,
@@ -728,21 +731,19 @@ def build_plan(stream: EncodedStream, codebook, method: str = "gap",
     units = jnp.asarray(stream.units)
     n_subseq = stream.n_subseq
     sps = stream.subseqs_per_seq
-    boundaries = jnp.arange(n_subseq, dtype=jnp.int32) * SUBSEQ_BITS
-    ends = boundaries + SUBSEQ_BITS
+    boundaries, ends = _window_bounds(n_subseq)
 
     if method == "gap":
         # A valid gap never exceeds SUBSEQ_BITS (the encoder stores the
         # offset of the first codeword start inside a 128-bit window, or
-        # the in-window distance to end-of-stream).  Clamp anything larger
-        # -- a corrupt gap array -- so sync starts stay inside the window
-        # their counts were computed for, and count the containment.
-        gaps = stream.gaps.astype(jnp.int32)
+        # the in-window distance to end-of-stream).  ``_gap_starts`` clamps
+        # anything larger -- a corrupt gap array -- so sync starts stay
+        # inside the window their counts were computed for; count the
+        # containment.
         if stream.gaps.size and int(np.asarray(stream.gaps).max(
                 initial=0)) > SUBSEQ_BITS:
             be.bump("decode_guard_trips")
-            gaps = jnp.minimum(gaps, SUBSEQ_BITS)
-        starts = boundaries + gaps
+        starts = _gap_starts(boundaries, stream.gaps)
         counts = be.count_fn(units, luts.dec_sym, luts.dec_len, starts, ends,
                              stream.total_bits, luts.max_len)
     elif method == "selfsync":
@@ -956,7 +957,7 @@ def decode(stream: EncodedStream, codebook, n_out: int, *,
                  inside the decode-write dispatch and the return value is
                  the reconstructed array, flat in C-order (the uint16
                  quant-code array is never materialized).  The transform's
-                 ``shape`` picks the 1-D/2-D/3-D reconstruction and
+                 ``shape`` picks the 1-D or N-D reconstruction and
                  ``out_dtype`` the output precision (f32 compute, one final
                  cast).  Supported for the "tile" and "padded" strategies
                  on backends registered with fused ops; the "tuned"

@@ -91,8 +91,21 @@ def _outlier_m_pad(n_out: int) -> int:
     return max(8, int(2 ** np.ceil(np.log2(max(n_out, 1) + 1))))
 
 
+@jax.jit
+def _value_stats(x):
+    """``(max(x) - min(x), max |x|)`` in x's dtype, as one program."""
+    return jnp.max(x) - jnp.min(x), jnp.max(jnp.abs(x))
+
+
+@jax.jit
+def _outlier_prefix(outlier):
+    """Inclusive prefix count of the flattened outlier mask, and its total."""
+    csum = he.prefix_sum(outlier.reshape(-1).astype(jnp.int32))
+    return csum, csum[-1]
+
+
 @partial(jax.jit, static_argnames=("m_pad",))
-def _gather_outliers(csum, resid_flat, m_pad: int):
+def _gather_outliers(csum, resid, m_pad: int):
     """Compact the outlier side list from an inclusive mask prefix sum.
 
     ``jnp.nonzero(size=...)`` lowers to a full-length scatter (serial on
@@ -106,7 +119,7 @@ def _gather_outliers(csum, resid_flat, m_pad: int):
     pos = jnp.searchsorted(csum, k, side="left").astype(jnp.int32)
     pos = jnp.where(k <= m, pos, -1)
     val = jnp.where(pos >= 0,
-                    resid_flat[jnp.clip(pos, 0)].astype(jnp.int32), 0)
+                    resid.reshape(-1)[jnp.clip(pos, 0)].astype(jnp.int32), 0)
     return pos, val
 
 
@@ -151,16 +164,15 @@ def compress(
     ``stats["encode_fallbacks"]``.
     """
     x = jnp.asarray(x)
+    span, max_abs = (float(v) for v in _value_stats(x))
     if mode == "rel":
-        rng = float(jnp.max(x) - jnp.min(x))
-        rng = rng if rng > 0 else 1.0
+        rng = span if span > 0 else 1.0
         abs_eb = eb * rng
     elif mode == "abs":
         rng = 1.0
         abs_eb = eb
     else:
         raise ValueError(f"unknown mode {mode!r}")
-    max_abs = float(jnp.max(jnp.abs(x)))
 
     ebe = hp.get_encode_backend(encode_backend)
     if ebe.device and encode_unsupported_reason(x, ebe) is not None:
@@ -174,10 +186,10 @@ def compress(
                 "error bound too small for int32 lattice; increase eb")
         codes, outlier, resid = ebe.quantize_fn(x, abs_eb, radius)
         codes_flat = codes.reshape(-1)
-        csum = jnp.cumsum(outlier.reshape(-1).astype(jnp.int32))
+        csum, n_outliers = _outlier_prefix(outlier)
         # One scalar sync sizes the side list; the gather stays on device.
-        m_pad = _outlier_m_pad(int(csum[-1]))
-        pos_pad, val_pad = _gather_outliers(csum, resid.reshape(-1), m_pad)
+        m_pad = _outlier_m_pad(int(n_outliers))
+        pos_pad, val_pad = _gather_outliers(csum, resid, m_pad)
         freq = ebe.hist_fn(codes_flat, 2 * radius)
     else:
         codes_np, outlier, resid = ebe.quantize_fn(x, abs_eb, radius)
@@ -231,22 +243,19 @@ FUSED_DTYPES = ("float32", "bfloat16", "float16")
 #: Widest fastest axis the row-tiled N-D epilogue provisions for (one tile
 #: must hold at least one whole row in VMEM).
 FUSED_MAX_COLS = 1 << 15
-#: Largest 3-D plane (rows * cols) the VMEM plane-carry scratch can hold.
-FUSED_MAX_PLANE = 1 << 20
 
 
 def fused_unsupported_reason(c: Compressed, backend, method: str,
                              strategy: str) -> "str | None":
     """Why the fused decode path cannot serve this tensor (None = it can).
 
-    The fused epilogue covers 1-D/2-D/3-D inverse Lorenzo (unit axes are
-    squeezed first -- ``kernels/ops.py:fused_squeeze``) over float32,
-    bfloat16 and float16 outputs (``FUSED_DTYPES``).  Still falling back
-    to the two-pass path (recorded in ``stats["fused_fallbacks"]``):
-    >3-D tensors, other dtypes, rows wider than ``FUSED_MAX_COLS``,
-    3-D planes larger than ``FUSED_MAX_PLANE`` (the VMEM plane-carry
-    bound), the sequential oracle method, the class-gathering "tuned"
-    strategy, and backends registered without fused ops.
+    The fused epilogue covers N-D inverse Lorenzo (unit axes are squeezed
+    first -- ``kernels/ops.py:fused_squeeze``) over float32, bfloat16 and
+    float16 outputs (``FUSED_DTYPES``).  Still falling back to the
+    two-pass path (recorded in ``stats["fused_fallbacks"]``): other
+    dtypes, rows wider than ``FUSED_MAX_COLS``, the sequential oracle
+    method, the class-gathering "tuned" strategy, and backends registered
+    without fused ops.
     """
     be = hp.get_backend(backend)
     if method == "naive_ref":
@@ -260,15 +269,9 @@ def fused_unsupported_reason(c: Compressed, backend, method: str,
         return (f"dtype {np.dtype(c.dtype)} not in fused set "
                 f"{FUSED_DTYPES}")
     sq = tuple(s for s in c.shape if s != 1)
-    if len(sq) > 3:
-        return (f"{len(sq)}-D Lorenzo reconstruction (fused epilogue "
-                f"covers up to 3-D)")
     if len(sq) >= 2 and sq[-1] > FUSED_MAX_COLS:
         return (f"fastest axis {sq[-1]} exceeds the per-tile row bound "
                 f"{FUSED_MAX_COLS}")
-    if len(sq) == 3 and sq[-2] * sq[-1] > FUSED_MAX_PLANE:
-        return (f"plane {sq[-2]}x{sq[-1]} exceeds the VMEM plane-carry "
-                f"bound {FUSED_MAX_PLANE}")
     return None
 
 
@@ -312,9 +315,9 @@ def decompress(
     (which adds plan caching and a fixed policy) instead of calling this
     directly.  Decoding goes through ``core.huffman.pipeline.decode``:
     ``backend`` in ``available_backends()`` selects the jnp reference or the
-    Pallas kernels (interpret mode on CPU), ``strategy`` in {"tuned", "tile",
-    "padded"} selects the decode-write variant, and ``plan`` may carry a
-    prebuilt ``DecoderPlan``.
+    Pallas kernels (compiled on a TPU, interpreted elsewhere), ``strategy``
+    in {"tuned", "tile", "padded"} selects the decode-write variant, and
+    ``plan`` may carry a prebuilt ``DecoderPlan``.
 
     ``fused=True`` requests the fused decode→dequantize→reconstruct path:
     phase 4 carries the decoded symbols straight through dequantization and

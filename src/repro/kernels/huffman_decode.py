@@ -1,184 +1,228 @@
 """Pallas TPU kernels for parallel Huffman decoding (gap-array phases).
 
-Two kernels:
+Three kernels, all built from ``kernels/common.py``:
 
-  * ``count_kernel`` -- phase 1 ("get output idx."): each lane decodes its
+  * ``count_subseq`` -- phase 1 ("get output idx."): each lane decodes its
     subsequence window and counts codeword starts.  Grid over blocks of
-    ``SS_BLOCK`` subsequences; each block's unit rows live in VMEM.
+    ``block_rows * 128`` subsequences.
 
-  * ``decode_tiles_kernel`` -- phase 2 (paper Alg. 1): grid over *output*
-    tiles of ``tile_syms`` symbols.  Each step decodes the statically bounded
-    set of subsequences overlapping its tile into a VMEM staging buffer and
+  * ``decode_tiles`` -- phase 2 (paper Alg. 1): grid over *output* tiles of
+    ``tile_syms`` symbols.  Each step decodes the statically bounded set of
+    subsequences overlapping its tile, stages their symbols in VMEM and
     emits one dense, aligned tile -- the TPU analogue of the shared-memory
     staged coalesced write.  ``tile_syms`` is the tunable the online tuner
     (core/huffman/pipeline.py) selects per compression-ratio class; the
     per-lane ``lut_base`` input selects a codebook inside a merged decode
     LUT for the batched multi-tensor path.
 
-TPU notes: the in-kernel gather (LUT lookup, per-lane unit fetch) lowers to
-Mosaic dynamic-gather over VMEM; the local scatter into the staging tile is
-a vector scatter confined to VMEM.  Validated in interpret mode (this
-container is CPU-only); BlockSpecs are written for real VMEM tiling.
+  * ``decode_padded`` -- the baseline without staging: the padded
+    ``(subsequence, MAX_SYMS)`` layout that ops-level compaction gathers,
+    the structural analogue of the original decoders' uncoalesced writes.
+
+Per-tile inputs are ``tile_inputs``: unit rows ``(ROW_UNITS, n_tiles, S,
+128)``, lane windows and LUT bases ``(n_tiles, S, 128)``, and an SMEM
+``meta`` row per tile holding every lane's output offset and symbol count
+(plus, for the fused kernels, the tile's outlier range).
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import common as C
 
-DEFAULT_SS_BLOCK = 256   # subsequences per count-kernel block
+DEFAULT_BLOCK_ROWS = C.SUBLANES   # count/padded kernels: 1024 lanes a block
 
 
-def count_kernel_body(rows_ref, start_ref, end_ref, sym_ref, len_ref,
-                      counts_ref, land_ref, *, max_len):
-    rows = rows_ref[...]
-    start = start_ref[...]
-    end = end_ref[...]
-    dec_sym = sym_ref[...]
-    dec_len = len_ref[...]
-    landing, counts = C.decode_window(rows, start, end, dec_sym, dec_len,
-                                      max_len, collect=False)
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class TileInputs:
+    """Per-tile lane metadata of the tile decode-write kernels."""
+
+    rows: jnp.ndarray       # uint32 (ROW_UNITS, n_tiles, S, 128)
+    start: jnp.ndarray      # int32 (n_tiles, S, 128) row-local window start
+    end: jnp.ndarray        # int32 (n_tiles, S, 128)
+    lut_base: jnp.ndarray   # int32 (n_tiles, S, 128)
+    meta: jnp.ndarray       # int32 (n_tiles, 1, W) SMEM: offsets, counts, ...
+
+    @property
+    def n_tiles(self) -> int:
+        return self.start.shape[0]
+
+    @property
+    def n_lanes(self) -> int:
+        return self.start.shape[1] * C.LANES
+
+
+def tile_in_specs(ti: TileInputs, lut) -> list:
+    """BlockSpecs of ``(rows, start, end, lut_base, meta, lut)``."""
+    s = ti.start.shape[1]
+    lane_spec = pl.BlockSpec((1, s, C.LANES), lambda t: (t, 0, 0))
+    return [
+        pl.BlockSpec((C.ROW_UNITS, 1, s, C.LANES), lambda t: (0, t, 0, 0)),
+        lane_spec, lane_spec, lane_spec,
+        pl.BlockSpec((1, 1, ti.meta.shape[2]), lambda t: (t, 0, 0),
+                     memory_space=pltpu.SMEM),
+        pl.BlockSpec(lut.shape, lambda t: (0, 0)),
+    ]
+
+
+def tile_scratch(ti: TileInputs, stage_shape) -> list:
+    """Symbol buffer, its transpose and the staging tile."""
+    s = ti.start.shape[1]
+    return [pltpu.VMEM((C.MAX_SYMS, s, C.LANES), jnp.int32),
+            pltpu.VMEM((s, C.LANES, C.MAX_SYMS), jnp.int32),
+            pltpu.VMEM(stage_shape, jnp.int32)]
+
+
+def decode_into_stage(rows_ref, start_ref, end_ref, lutb_ref, meta_ref,
+                      lut_ref, y_ref, yt_ref, stage_ref, *, max_len: int,
+                      lut_size: int, cols: int, tile: int, ss_max: int):
+    """Decode one tile's lanes and stage their symbols (``C.stage_segments``
+    layout).  Shared body of the plain and fused tile kernels; lanes past
+    ``ss_max`` carry no window."""
+    rows = [rows_ref[k, 0] for k in range(C.ROW_UNITS)]
+    C.decode_lanes(rows, start_ref[0], end_ref[0], lut_ref, max_len=max_len,
+                   lut_size=lut_size, lut_base=lutb_ref[0], y_ref=y_ref)
+    C.transpose_symbols(y_ref, yt_ref)
+    stage_ref[...] = jnp.zeros(stage_ref.shape, jnp.int32)
+    C.stage_segments(yt_ref, meta_ref, stage_ref,
+                     n_lanes=start_ref.shape[1] * C.LANES, n_valid=ss_max,
+                     cols=cols, tile=tile)
+
+
+def _block_lanes_specs(block_rows: int):
+    return (pl.BlockSpec((C.ROW_UNITS, block_rows, C.LANES),
+                         lambda b: (0, b, 0)),
+            pl.BlockSpec((block_rows, C.LANES), lambda b: (b, 0)))
+
+
+def _count_kernel(rows_ref, start_ref, end_ref, lut_ref, counts_ref,
+                  land_ref, *, max_len, lut_size):
+    rows = [rows_ref[k] for k in range(C.ROW_UNITS)]
+    landing, counts = C.decode_lanes(rows, start_ref[...], end_ref[...],
+                                     lut_ref, max_len=max_len,
+                                     lut_size=lut_size)
     counts_ref[...] = counts
     land_ref[...] = landing
 
 
 @functools.partial(
-    jax.jit, static_argnames=("max_len", "ss_block", "interpret"))
-def count_subseq(rows, start_local, end_local, dec_sym, dec_len,
-                 max_len: int, ss_block: int = DEFAULT_SS_BLOCK,
-                 interpret: bool = True):
+    jax.jit,
+    static_argnames=("max_len", "lut_size", "block_rows"))
+def count_subseq(rows, start_local, end_local, lut, max_len: int,
+                 lut_size: int, block_rows: int = DEFAULT_BLOCK_ROWS):
     """Per-subsequence codeword counts + landing positions.
 
-    rows: uint32[n_subseq, ROW_UNITS]; start/end_local: int32[n_subseq]
-    (row-local bit windows).  Returns (counts, landing) int32[n_subseq].
+    rows: uint32 ``(ROW_UNITS, N / 128, 128)``; start/end_local: int32
+    ``(N / 128, 128)`` row-local bit windows; ``lut`` the packed decode
+    table (``C.pack_lut``).  ``N / 128`` must be a ``block_rows`` multiple.
+    Returns ``(counts, landing)`` int32 ``(N / 128, 128)``.
     """
-    n = rows.shape[0]
-    assert n % ss_block == 0, (n, ss_block)
-    grid = (n // ss_block,)
-    lut = dec_sym.shape[0]
-    kernel = functools.partial(count_kernel_body, max_len=max_len)
-    counts, landing = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((ss_block, C.ROW_UNITS), lambda b: (b, 0)),
-            pl.BlockSpec((ss_block,), lambda b: (b,)),
-            pl.BlockSpec((ss_block,), lambda b: (b,)),
-            pl.BlockSpec((lut,), lambda b: (0,)),
-            pl.BlockSpec((lut,), lambda b: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((ss_block,), lambda b: (b,)),
-            pl.BlockSpec((ss_block,), lambda b: (b,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(rows, start_local, end_local, dec_sym, dec_len)
-    return counts, landing
+    n_rows = start_local.shape[0]
+    assert n_rows % block_rows == 0, (n_rows, block_rows)
+    rows_spec, lane_spec = _block_lanes_specs(block_rows)
+    out = jax.ShapeDtypeStruct(start_local.shape, jnp.int32)
+    return pl.pallas_call(
+        functools.partial(_count_kernel, max_len=max_len, lut_size=lut_size),
+        grid=(n_rows // block_rows,),
+        in_specs=[rows_spec, lane_spec, lane_spec,
+                  pl.BlockSpec(lut.shape, lambda b: (0, 0))],
+        out_specs=[lane_spec, lane_spec],
+        out_shape=[out, out],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=C.use_interpreter(),
+    )(rows, start_local, end_local, lut)
 
 
-def decode_tiles_kernel_body(rows_ref, start_ref, end_ref, off_ref, lut_ref,
-                             sym_ref, len_ref, out_ref, *, max_len,
-                             tile_syms):
-    # VMEM staging: each lane decodes its window and scatters its symbols to
-    # tile-local positions (C.stage_tile); one dense aligned tile comes out.
-    out_ref[0] = C.stage_tile(
-        rows_ref[0],              # (ss_max, ROW_UNITS)
-        start_ref[0],             # (ss_max,) row-local start bits
-        end_ref[0],               # (ss_max,)
-        off_ref[0],               # (ss_max,) tile-local output offsets
-        lut_ref[0],               # (ss_max,) per-lane LUT base offsets
-        sym_ref[...], len_ref[...], max_len, tile_syms)
+def _decode_tiles_kernel(rows_ref, start_ref, end_ref, lutb_ref, meta_ref,
+                         lut_ref, out_ref, y_ref, yt_ref, stage_ref, *,
+                         max_len, lut_size, tile, ss_max):
+    decode_into_stage(rows_ref, start_ref, end_ref, lutb_ref, meta_ref,
+                      lut_ref, y_ref, yt_ref, stage_ref, max_len=max_len,
+                      lut_size=lut_size, cols=C.LANES, tile=tile,
+                      ss_max=ss_max)
+    out_ref[0] = stage_ref[0].astype(out_ref.dtype)
+
+
+def tile_rows(tile_syms: int) -> int:
+    """Rows of 128 symbols a flat tile is staged in (whole vregs)."""
+    return C.round_up(-(-tile_syms // C.LANES), C.SUBLANES)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("max_len", "tile_syms", "ss_max", "n_out", "interpret"))
-def decode_tiles(rows, start_local, end_local, off_local, lut_base, dec_sym,
-                 dec_len, max_len: int, tile_syms: int, ss_max: int,
-                 n_out: int, interpret: bool = True):
+    static_argnames=("max_len", "lut_size", "tile_syms", "ss_max"))
+def decode_tiles(ti: TileInputs, lut, max_len: int, lut_size: int,
+                 tile_syms: int, ss_max: int):
     """Tile-centric decode+write.
 
-    rows:        uint32[n_tiles, ss_max, ROW_UNITS]
-    start/end:   int32[n_tiles, ss_max]   (row-local windows)
-    off_local:   int32[n_tiles, ss_max]   (output offset - tile base;
-                 invalid lanes carry ``tile_syms``)
-    lut_base:    int32[n_tiles, ss_max]   (per-lane offset into a merged
-                 decode LUT; all-zero for single-codebook decodes)
-    Returns uint16[n_out].
+    Returns uint16 ``(n_tiles, tile_rows, 128)``: tile ``t`` holds output
+    symbols ``[t * tile_syms, (t + 1) * tile_syms)`` flat in its first
+    ``tile_syms`` entries (zeros past them).
     """
-    n_tiles = rows.shape[0]
-    lut = dec_sym.shape[0]
-    kernel = functools.partial(decode_tiles_kernel_body, max_len=max_len,
-                               tile_syms=tile_syms)
-    tiles = pl.pallas_call(
-        kernel,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((1, ss_max, C.ROW_UNITS), lambda t: (t, 0, 0)),
-            pl.BlockSpec((1, ss_max), lambda t: (t, 0)),
-            pl.BlockSpec((1, ss_max), lambda t: (t, 0)),
-            pl.BlockSpec((1, ss_max), lambda t: (t, 0)),
-            pl.BlockSpec((1, ss_max), lambda t: (t, 0)),
-            pl.BlockSpec((lut,), lambda t: (0,)),
-            pl.BlockSpec((lut,), lambda t: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, tile_syms), lambda t: (t, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, tile_syms), jnp.uint16),
-        interpret=interpret,
-    )(rows, start_local, end_local, off_local, lut_base, dec_sym, dec_len)
-    return tiles.reshape(-1)[:n_out]
+    tr = tile_rows(tile_syms)
+    block = (1, tr, C.LANES)
+    return pl.pallas_call(
+        functools.partial(_decode_tiles_kernel, max_len=max_len,
+                          lut_size=lut_size, tile=tile_syms, ss_max=ss_max),
+        grid=(ti.n_tiles,),
+        in_specs=tile_in_specs(ti, lut),
+        out_specs=pl.BlockSpec(block, lambda t: (t, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((ti.n_tiles, tr, C.LANES), jnp.uint16),
+        scratch_shapes=tile_scratch(ti, block),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=C.use_interpreter(),
+    )(ti.rows, ti.start, ti.end, ti.lut_base, ti.meta, lut)
 
 
-def decode_padded_kernel_body(rows_ref, start_ref, end_ref, sym_ref, len_ref,
-                              out_ref, counts_ref, *, max_len):
-    """Baseline decode+write without staging: emits the padded
-    (subseq, MAX_SYMS) layout that ops-level compaction then gathers --
-    the structural analogue of the original decoders' uncoalesced writes."""
-    rows = rows_ref[...]
-    start = start_ref[...]
-    end = end_ref[...]
-    _, counts, padded = C.decode_window(rows, start, end, sym_ref[...],
-                                        len_ref[...], max_len, collect=True)
-    out_ref[...] = padded
+def _decode_padded_kernel(rows_ref, start_ref, end_ref, lut_ref, out_ref,
+                          counts_ref, y_ref, *, max_len, lut_size):
+    rows = [rows_ref[k] for k in range(C.ROW_UNITS)]
+    y_ref[...] = jnp.zeros(y_ref.shape, jnp.int32)
+    _, counts = C.decode_lanes(rows, start_ref[...], end_ref[...], lut_ref,
+                               max_len=max_len, lut_size=lut_size,
+                               y_ref=y_ref)
+    for s in range(y_ref.shape[1]):
+        out_ref[s * C.LANES:(s + 1) * C.LANES, :] = (
+            y_ref[:, s, :].T.astype(out_ref.dtype))
     counts_ref[...] = counts
 
 
 @functools.partial(
-    jax.jit, static_argnames=("max_len", "ss_block", "interpret"))
-def decode_padded(rows, start_local, end_local, dec_sym, dec_len,
-                  max_len: int, ss_block: int = DEFAULT_SS_BLOCK,
-                  interpret: bool = True):
-    n = rows.shape[0]
-    assert n % ss_block == 0
-    lut = dec_sym.shape[0]
-    kernel = functools.partial(decode_padded_kernel_body, max_len=max_len)
-    padded, counts = pl.pallas_call(
-        kernel,
-        grid=(n // ss_block,),
-        in_specs=[
-            pl.BlockSpec((ss_block, C.ROW_UNITS), lambda b: (b, 0)),
-            pl.BlockSpec((ss_block,), lambda b: (b,)),
-            pl.BlockSpec((ss_block,), lambda b: (b,)),
-            pl.BlockSpec((lut,), lambda b: (0,)),
-            pl.BlockSpec((lut,), lambda b: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((ss_block, C.MAX_SYMS), lambda b: (b, 0)),
-            pl.BlockSpec((ss_block,), lambda b: (b,)),
-        ],
+    jax.jit,
+    static_argnames=("max_len", "lut_size", "block_rows"))
+def decode_padded(rows, start_local, end_local, lut, max_len: int,
+                  lut_size: int, block_rows: int = DEFAULT_BLOCK_ROWS):
+    """Padded baseline: returns (uint16 ``(N, MAX_SYMS)`` symbols, int32
+    ``(N / 128, 128)`` counts); inputs as :func:`count_subseq`."""
+    n_rows = start_local.shape[0]
+    assert n_rows % block_rows == 0, (n_rows, block_rows)
+    rows_spec, lane_spec = _block_lanes_specs(block_rows)
+    lanes = block_rows * C.LANES
+    return pl.pallas_call(
+        functools.partial(_decode_padded_kernel, max_len=max_len,
+                          lut_size=lut_size),
+        grid=(n_rows // block_rows,),
+        in_specs=[rows_spec, lane_spec, lane_spec,
+                  pl.BlockSpec(lut.shape, lambda b: (0, 0))],
+        out_specs=[pl.BlockSpec((lanes, C.MAX_SYMS), lambda b: (b, 0)),
+                   lane_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((n, C.MAX_SYMS), jnp.uint16),
-            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((n_rows * C.LANES, C.MAX_SYMS), jnp.uint16),
+            jax.ShapeDtypeStruct(start_local.shape, jnp.int32),
         ],
-        interpret=interpret,
-    )(rows, start_local, end_local, dec_sym, dec_len)
-    return padded, counts
+        scratch_shapes=[pltpu.VMEM((C.MAX_SYMS, block_rows, C.LANES),
+                                   jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=C.use_interpreter(),
+    )(rows, start_local, end_local, lut)

@@ -1,20 +1,26 @@
 """Pallas kernel for the self-synchronization phase (W&S, paper §IV-A).
 
-Grid over sequences; lanes are the sequence's subsequences.  Each round every
-lane decodes its 128-bit window from its current candidate offset and hands
-the landing position to the next lane; the block reaches a fixed point when
+Lanes are subsequences in the ``(rows, 128)`` layout of
+``kernels/common.py``; a sequence is ``subseqs_per_seq`` consecutive lanes
+of one row, so a block of ``block_rows`` rows holds
+``block_rows * 128 / subseqs_per_seq`` sequences.  Each round every lane
+decodes its 128-bit window from its current candidate offset and hands the
+landing position to the next lane (a one-lane rotation); the first lane of
+every sequence keeps its head offset.  The block reaches a fixed point when
 no offset changes.
 
-The paper's optimization -- exiting the block as soon as *all* lanes have
-validated their sync point (`__all_sync`) instead of spinning to the
-worst-case bound -- maps to the ``while_loop``-with-convergence-predicate
-here; the un-optimized variant (``early_exit=False``) runs the worst-case
-``subseqs_per_seq`` rounds unconditionally.  Both are kept so the benchmark
-can reproduce the paper's ~11% phase-1 win.
+The paper's optimization -- exiting as soon as *all* lanes have validated
+their sync point (``__all_sync``) instead of spinning to the worst-case
+bound -- maps to the ``while_loop``-with-convergence-predicate here; the
+un-optimized variant (``early_exit=False``) runs the worst-case
+``subseqs_per_seq`` rounds unconditionally.  Both are kept so the
+benchmark can reproduce the paper's ~11% phase-1 win.  Extra rounds past a
+sequence's fixed point leave it unchanged, so sharing a block's round count
+among its sequences gives the per-sequence answer.
 
 Inter-sequence synchronization (phase 2) chains sequence-head offsets at the
-ops level (`repro.kernels.ops.selfsync_sync`) -- a separate launch, as in the
-paper.
+ops level (``repro.kernels.ops.selfsync_sync``) -- a separate launch, as in
+the paper.
 """
 
 from __future__ import annotations
@@ -24,95 +30,91 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import common as C
 
 
-def selfsync_kernel_body(rows_ref, head_ref, end_ref, sym_ref, len_ref,
-                         start_ref, counts_ref, land_ref, rounds_ref, *,
-                         max_len, early_exit, subseqs_per_seq):
-    rows = rows_ref[0]            # (SS, ROW_UNITS)
-    head = head_ref[0]            # (1,) int32: candidate offset of lane 0
-    end = end_ref[0]              # (SS,) row-local window ends
-    dec_sym = sym_ref[...]
-    dec_len = len_ref[...]
-    ss = rows.shape[0]
-
-    start0 = jnp.zeros((ss,), jnp.int32).at[0].set(head[0])
+def _selfsync_kernel(rows_ref, head_ref, end_ref, lut_ref, start_ref,
+                     counts_ref, land_ref, rounds_ref, *, max_len, lut_size,
+                     early_exit, subseqs_per_seq):
+    rows = [rows_ref[k] for k in range(C.ROW_UNITS)]
+    end = end_ref[...]
+    head = head_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, head.shape, 1)
+    first = (lane & (subseqs_per_seq - 1)) == 0
 
     def round_fn(start):
-        landing, counts = C.decode_window(rows, start, end, dec_sym, dec_len,
-                                          max_len, collect=False)
-        # landing is local to each lane's row; lane j's landing lies in
-        # [128, 128+max_len) => offset (landing - 128) into lane j+1's row.
-        prop = jnp.concatenate([start[:1], landing[:-1] - 128])
+        landing, counts = C.decode_lanes(rows, start, end, lut_ref,
+                                         max_len=max_len, lut_size=lut_size)
+        # Lane j's landing lies in [128, 128 + max_len) of its row, i.e.
+        # offset (landing - 128) into lane j + 1's row.
+        prop = jnp.where(first, head, pltpu.roll(landing, 1, 1) - 128)
         return prop, landing, counts
 
+    zero = jnp.zeros(head.shape, jnp.int32)
     if early_exit:
         def cond(state):
-            start, _, _, changed, rounds = state
+            _, _, _, changed, rounds = state
             return jnp.logical_and(changed, rounds < subseqs_per_seq)
 
         def body(state):
             start, _, _, _, rounds = state
             new_start, landing, counts = round_fn(start)
-            changed = jnp.any(new_start != start)
-            return new_start, landing, counts, changed, rounds + 1
+            return (new_start, landing, counts, jnp.any(new_start != start),
+                    rounds + 1)
 
-        zero = jnp.zeros((ss,), jnp.int32)
         start, landing, counts, _, rounds = jax.lax.while_loop(
-            cond, body, (start0, zero, zero, jnp.bool_(True), jnp.int32(0)))
+            cond, body, (head, zero, zero, jnp.bool_(True), jnp.int32(0)))
     else:
-        start, landing, counts = start0, None, None
-        for _ in range(subseqs_per_seq):
-            start, landing, counts = round_fn(start)
+        start, landing, counts = jax.lax.fori_loop(
+            0, subseqs_per_seq, lambda _, s: round_fn(s[0]),
+            (head, zero, zero))
         rounds = jnp.int32(subseqs_per_seq)
 
-    start_ref[0] = start
-    counts_ref[0] = counts
-    land_ref[0] = landing
-    rounds_ref[0] = rounds[None]
+    start_ref[...] = start
+    counts_ref[...] = counts
+    land_ref[...] = landing
+    rounds_ref[...] = jnp.full(head.shape, rounds, jnp.int32)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("max_len", "subseqs_per_seq", "early_exit", "interpret"))
-def selfsync_intra(rows, heads, end_local, dec_sym, dec_len, max_len: int,
+    static_argnames=("max_len", "lut_size", "subseqs_per_seq", "early_exit",
+                     "block_rows"))
+def selfsync_intra(rows, heads, end_local, lut, max_len: int, lut_size: int,
                    subseqs_per_seq: int, early_exit: bool = True,
-                   interpret: bool = True):
+                   block_rows: int = C.SUBLANES):
     """Per-sequence sync discovery.
 
-    rows: uint32[n_seq, SS, ROW_UNITS]; heads: int32[n_seq, 1] candidate
-    offsets for each sequence's first subsequence; end_local: int32[n_seq, SS].
-    Returns (start_local, counts, landing, rounds) with shapes
-    ([n_seq, SS], [n_seq, SS], [n_seq, SS], [n_seq, 1]).
+    rows: uint32 ``(ROW_UNITS, R, 128)``; heads: int32 ``(R, 128)`` holding
+    each sequence's candidate head offset in its first lane (other lanes
+    ignored); end_local: int32 ``(R, 128)``; ``lut`` the packed decode table.
+    ``subseqs_per_seq`` must be a power of two dividing 128 and ``R`` a
+    ``block_rows`` multiple.  Returns ``(start_local, counts, landing,
+    rounds)``, all int32 ``(R, 128)`` (``rounds`` is per block, repeated in
+    every lane).
     """
-    n_seq, ss, _ = rows.shape
-    lut = dec_sym.shape[0]
-    kernel = functools.partial(
-        selfsync_kernel_body, max_len=max_len, early_exit=early_exit,
-        subseqs_per_seq=subseqs_per_seq)
+    if C.LANES % subseqs_per_seq or subseqs_per_seq & (subseqs_per_seq - 1):
+        raise ValueError(f"self-sync kernel needs subseqs_per_seq to be a "
+                         f"power of two dividing {C.LANES}, got "
+                         f"{subseqs_per_seq}")
+    n_rows = heads.shape[0]
+    assert n_rows % block_rows == 0, (n_rows, block_rows)
+    lane_spec = pl.BlockSpec((block_rows, C.LANES), lambda b: (b, 0))
+    out = jax.ShapeDtypeStruct(heads.shape, jnp.int32)
     return pl.pallas_call(
-        kernel,
-        grid=(n_seq,),
-        in_specs=[
-            pl.BlockSpec((1, ss, C.ROW_UNITS), lambda s: (s, 0, 0)),
-            pl.BlockSpec((1, 1), lambda s: (s, 0)),
-            pl.BlockSpec((1, ss), lambda s: (s, 0)),
-            pl.BlockSpec((lut,), lambda s: (0,)),
-            pl.BlockSpec((lut,), lambda s: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, ss), lambda s: (s, 0)),
-            pl.BlockSpec((1, ss), lambda s: (s, 0)),
-            pl.BlockSpec((1, ss), lambda s: (s, 0)),
-            pl.BlockSpec((1, 1), lambda s: (s, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_seq, ss), jnp.int32),
-            jax.ShapeDtypeStruct((n_seq, ss), jnp.int32),
-            jax.ShapeDtypeStruct((n_seq, ss), jnp.int32),
-            jax.ShapeDtypeStruct((n_seq, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(rows, heads, end_local, dec_sym, dec_len)
+        functools.partial(_selfsync_kernel, max_len=max_len,
+                          lut_size=lut_size, early_exit=early_exit,
+                          subseqs_per_seq=subseqs_per_seq),
+        grid=(n_rows // block_rows,),
+        in_specs=[pl.BlockSpec((C.ROW_UNITS, block_rows, C.LANES),
+                               lambda b: (0, b, 0)),
+                  lane_spec, lane_spec,
+                  pl.BlockSpec(lut.shape, lambda b: (0, 0))],
+        out_specs=[lane_spec] * 4,
+        out_shape=[out] * 4,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=C.use_interpreter(),
+    )(rows, heads, end_local, lut)

@@ -1,13 +1,13 @@
-"""Pallas kernels for the Lorenzo transform (cuSZ dual-quant, DESIGN.md §3).
+"""Pallas kernels for the 1-D Lorenzo transform (cuSZ dual-quant).
 
 ``quantize1d`` is fully parallel (dual-quantization removed the loop-carried
-dependence); ``reconstruct1d`` is the inverse prefix sum, implemented with a
-block-local cumsum plus a carry kept in VMEM scratch across the sequential
-grid -- the standard single-pass chained-scan structure.
+dependence); ``reconstruct1d`` is the inverse prefix sum, a block-local
+scan plus a carry kept in VMEM scratch across the sequential grid -- the
+standard single-pass chained-scan structure.
 
-2-D/3-D Lorenzo is composed at the ops level from per-axis applications
-(the per-axis pass is the same 1-D kernel applied to rows); see
-``repro.kernels.ops.lorenzo_*``.
+Both work on the flat signal laid out as ``(rows, 128)`` and cut into
+blocks of ``block_rows`` rows.  2-D/3-D Lorenzo is composed at the ops
+level (``repro.kernels.ops.lorenzo_*``).
 """
 
 from __future__ import annotations
@@ -19,17 +19,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import common as C
 
-def _quant_kernel(x_ref, xprev_ref, teb_ref, o_code_ref, o_out_ref,
+#: Rows per block: a multiple of 32, the int8 tile of the outlier mask.
+MAX_BLOCK_ROWS = 512
+
+
+def block_rows(n: int) -> int:
+    """Rows per block for an ``n``-element signal."""
+    return min(MAX_BLOCK_ROWS, C.round_up(max(-(-n // C.LANES), 1), 32))
+
+
+def _quant_kernel(teb_ref, x_ref, xprev_ref, o_code_ref, o_out_ref,
                   o_resid_ref, *, radius):
     # two_eb arrives as a runtime input: XLA strength-reduces division by a
     # *constant* to a reciprocal multiply, which flips lattice ties vs the
     # jnp oracle (whose eb is a traced argument -> true division).
-    x = x_ref[...]
-    xp = xprev_ref[...]
     two_eb = teb_ref[0]
-    q = jnp.round(x / two_eb).astype(jnp.int32)
-    qp = jnp.round(xp / two_eb).astype(jnp.int32)
+    q = jnp.round(x_ref[...] / two_eb).astype(jnp.int32)
+    qp = jnp.round(xprev_ref[...] / two_eb).astype(jnp.int32)
     d = q - qp
     code = d + radius
     outlier = (code < 0) | (code >= 2 * radius)
@@ -38,65 +46,62 @@ def _quant_kernel(x_ref, xprev_ref, teb_ref, o_code_ref, o_out_ref,
     o_resid_ref[...] = d
 
 
-@functools.partial(
-    jax.jit, static_argnames=("eb", "radius", "block", "interpret"))
-def quantize1d(x, eb, radius: int = 512, block: int = 4096,
-               interpret: bool = True):
-    """1-D dual-quant Lorenzo: returns (codes u16, outlier i8, residual i32).
+@functools.partial(jax.jit, static_argnames=("radius"))
+def quantize1d(x, xprev, two_eb, radius: int = 512):
+    """1-D dual-quant Lorenzo over ``(rows, 128)`` float32 ``x``.
 
-    The predecessor element crosses block boundaries, so the shifted copy is
-    passed as a second input (built by ops with a cheap roll).
+    ``xprev`` is ``x`` shifted by one element (0 before the first), so the
+    predecessor crosses block boundaries for free; ``two_eb`` is float32
+    ``(1,)``.  ``rows`` must be a ``block_rows`` multiple.  Returns (codes
+    uint16, outlier int8, residual int32), each ``(rows, 128)``.
     """
-    n = x.shape[0]
-    assert n % block == 0
-    xprev = jnp.roll(x, 1).at[0].set(0.0)
-    grid = (n // block,)
-    two_eb = jnp.full((1,), 2.0 * eb, jnp.float32)
+    rows = x.shape[0]
+    br = block_rows(rows * C.LANES)
+    assert rows % br == 0, (rows, br)
+    spec = pl.BlockSpec((br, C.LANES), lambda i: (i, 0))
     return pl.pallas_call(
         functools.partial(_quant_kernel, radius=radius),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.uint16),
-            jax.ShapeDtypeStruct((n,), jnp.int8),
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(x, xprev, two_eb)
+        grid=(rows // br,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), spec, spec],
+        out_specs=[spec, spec, spec],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, jnp.uint16),
+                   jax.ShapeDtypeStruct(x.shape, jnp.int8),
+                   jax.ShapeDtypeStruct(x.shape, jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=C.use_interpreter(),
+    )(two_eb, x, xprev)
 
 
-def _recon_kernel(d_ref, o_ref, carry, *, two_eb):
+def _recon_kernel(teb_ref, d_ref, o_ref, carry):
     @pl.when(pl.program_id(0) == 0)
     def _init():
-        carry[0] = jnp.int32(0)
+        carry[...] = jnp.zeros(carry.shape, jnp.int32)
 
-    d = d_ref[...].astype(jnp.int32)
-    q = jnp.cumsum(d) + carry[0]
-    carry[0] = q[-1]
-    o_ref[...] = q.astype(jnp.float32) * two_eb
+    q = C.flat_scan(d_ref[...]) + carry[...]
+    carry[...] = jnp.broadcast_to(q[-1:, -1:], carry.shape)
+    o_ref[...] = q.astype(jnp.float32) * teb_ref[0]
 
 
-@functools.partial(jax.jit, static_argnames=("eb", "block", "interpret"))
-def reconstruct1d(d, eb, block: int = 4096, interpret: bool = True):
-    """Inverse 1-D Lorenzo: chained block cumsum, x = 2*eb * prefix(d)."""
-    n = d.shape[0]
-    assert n % block == 0
-    two_eb = float(2.0 * eb)
+@jax.jit
+def reconstruct1d(d, two_eb):
+    """Inverse 1-D Lorenzo: chained block scan, ``x = 2*eb * prefix(d)``.
+
+    ``d`` int32 ``(rows, 128)`` (a ``block_rows`` multiple), ``two_eb``
+    float32 ``(1,)``; returns float32 ``(rows, 128)``.
+    """
+    rows = d.shape[0]
+    br = block_rows(rows * C.LANES)
+    assert rows % br == 0, (rows, br)
+    spec = pl.BlockSpec((br, C.LANES), lambda i: (i, 0))
     return pl.pallas_call(
-        functools.partial(_recon_kernel, two_eb=two_eb),
-        grid=(n // block,),
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((1,), jnp.int32)],
-        interpret=interpret,
-    )(d)
+        _recon_kernel,
+        grid=(rows // br,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(d.shape, jnp.float32),
+        scratch_shapes=[pltpu.VMEM((1, C.LANES), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=C.use_interpreter(),
+    )(two_eb, d)

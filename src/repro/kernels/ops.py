@@ -10,7 +10,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core.huffman import decode as hd
 from repro.core.huffman import encode as he
@@ -39,76 +38,98 @@ def _subseq_windows(start_abs, end_abs, total_bits):
     return ids, start_local, end_local
 
 
+def _lane_block(ids, start_local, end_local, block_rows: int):
+    """Pad per-subsequence lane arrays to whole ``block_rows * 128`` blocks
+    and lay them out as ``(rows, 128)``; pad lanes have an empty window."""
+    n = ids.shape[0]
+    pad = (-n) % (block_rows * C.LANES) or (0 if n else block_rows * C.LANES)
+    z = jnp.zeros((pad,), jnp.int32)
+    ids, start_local, end_local = (jnp.concatenate([a, z]).reshape(-1, C.LANES)
+                                   for a in (ids, start_local, end_local))
+    return ids, start_local, end_local
+
+
+@partial(jax.jit, static_argnames=("max_len",))
 def subseq_counts(units, dec_sym, dec_len, start_abs, end_abs, total_bits,
-                  max_len: int, interpret: bool = True):
+                  max_len: int):
+    """Kernel-backed phase 1: codeword counts and landing positions."""
     ids, start_local, end_local = _subseq_windows(start_abs, end_abs,
                                                   total_bits)
     n = ids.shape[0]
-    ss_block = _dec.DEFAULT_SS_BLOCK
-    pad = (-n) % ss_block
-    if pad:
-        ids = jnp.concatenate([ids, jnp.zeros(pad, jnp.int32)])
-        start_local = jnp.concatenate([start_local, jnp.zeros(pad, jnp.int32)])
-        # start == end => inactive padding lanes
-        end_local = jnp.concatenate([end_local, jnp.zeros(pad, jnp.int32)])
-    rows = C.gather_subseq_rows(jnp.asarray(units), ids)
+    ids, start_local, end_local = _lane_block(ids, start_local, end_local,
+                                              _dec.DEFAULT_BLOCK_ROWS)
+    rows = C.lane_rows(units, ids)
     counts, landing = _dec.count_subseq(rows, start_local, end_local,
-                                        dec_sym, dec_len, max_len,
-                                        interpret=interpret)
-    return counts[:n], landing[:n]
+                                        C.pack_lut(dec_sym, dec_len), max_len,
+                                        int(dec_sym.shape[0]))
+    return counts.reshape(-1)[:n], landing.reshape(-1)[:n]
 
 
-def _tile_inputs(units, start_bits, end_bits, offsets, total_bits,
-                 n_out: int, tile_syms: int, ss_max: int, lut_base=None):
+def tile_inputs(units, start_bits, end_bits, offsets, total_bits,
+                n_out: int, tile_syms: int, ss_max: int, lut_base=None,
+                outlier_pos=None) -> _dec.TileInputs:
     """Per-tile lane metadata shared by the plain and fused tile decoders.
 
     Maps each output tile to the (statically bounded) range of subsequences
     overlapping it and converts their absolute bit windows to row-local
-    coordinates.  Returns (rows, start_local, end_local, off_local, lut_tile).
+    coordinates.  ``offsets`` is the exclusive prefix sum of the per-window
+    symbol counts (``n_subseq + 1`` entries).  With ``outlier_pos`` (the
+    -1-padded side list) every tile's ``meta`` row ends with the range of
+    the side list inside the tile.
     """
-    units = jnp.asarray(units)
     n_subseq = start_bits.shape[0]
     n_tiles = (n_out + tile_syms - 1) // tile_syms
     tile_base = jnp.arange(n_tiles, dtype=jnp.int32) * tile_syms
     s0 = jnp.clip(jnp.searchsorted(offsets, tile_base, side="right") - 1,
                   0, n_subseq - 1)
 
-    lane = jnp.arange(ss_max, dtype=jnp.int32)
+    n_lanes = C.lane_block(ss_max)
+    lane = jnp.arange(n_lanes, dtype=jnp.int32)
     subs_raw = s0[:, None] + lane[None, :]
-    valid = subs_raw < n_subseq
+    valid = (subs_raw < n_subseq) & (lane < ss_max)[None, :]
     subs = jnp.clip(subs_raw, 0, n_subseq - 1)
 
     ids, start_local, end_local = _subseq_windows(
         start_bits[subs], end_bits[subs], total_bits)
-    # Invalid (clipped) lanes: no work, out-of-tile offset.
+    # Invalid (clipped) lanes: no work, no symbols.
     start_local = jnp.where(valid, start_local, 0)
     end_local = jnp.where(valid, end_local, 0)
-    off_local = jnp.where(valid, offsets[subs] - tile_base[:, None],
-                          tile_syms).astype(jnp.int32)
+    offsets = jnp.asarray(offsets, jnp.int32)
+    off_local = jnp.where(valid, offsets[subs] - tile_base[:, None], 0)
+    count = jnp.where(valid, offsets[subs + 1] - offsets[subs], 0)
     if lut_base is None:
         lut_tile = jnp.zeros(subs.shape, jnp.int32)
     else:
         lut_tile = jnp.where(valid, lut_base[subs], 0).astype(jnp.int32)
 
-    rows = C.gather_subseq_rows(units, ids)
-    return rows, start_local, end_local, off_local, lut_tile
+    meta = [off_local, count]
+    if outlier_pos is not None:
+        r = C.outlier_ranges(outlier_pos,
+                             jnp.append(tile_base, n_tiles * tile_syms))
+        meta.append(jnp.stack([r[:-1], r[1:]], axis=1))
+    lanes = (n_tiles, n_lanes // C.LANES, C.LANES)
+    return _dec.TileInputs(
+        rows=C.lane_rows(units, ids.reshape(lanes)),
+        start=start_local.reshape(lanes), end=end_local.reshape(lanes),
+        lut_base=lut_tile.reshape(lanes),
+        meta=jnp.concatenate(meta, axis=1).astype(jnp.int32)[:, None, :])
 
 
+@partial(jax.jit, static_argnames=("max_len", "n_out", "tile_syms", "ss_max"))
 def decode_write_tiles(units, dec_sym, dec_len, start_bits, end_bits, offsets,
                        total_bits, max_len: int, n_out: int, tile_syms: int,
-                       ss_max: int, lut_base=None, interpret: bool = True):
+                       ss_max: int, lut_base=None):
     """Kernel-backed phase 4; signature-compatible with the jnp reference
     ``core.huffman.decode.decode_write_tiles`` (so the tuner can inject it).
 
     ``lut_base`` (optional int32[n_subseq]) selects a per-subsequence decode
     table inside a merged LUT (the batched multi-tensor path).
     """
-    rows, start_local, end_local, off_local, lut_tile = _tile_inputs(
-        units, start_bits, end_bits, offsets, total_bits, n_out, tile_syms,
-        ss_max, lut_base)
-    return _dec.decode_tiles(rows, start_local, end_local, off_local,
-                             lut_tile, dec_sym, dec_len, max_len, tile_syms,
-                             ss_max, n_out, interpret=interpret)
+    ti = tile_inputs(units, start_bits, end_bits, offsets, total_bits, n_out,
+                     tile_syms, ss_max, lut_base)
+    tiles = _dec.decode_tiles(ti, C.pack_lut(dec_sym, dec_len), max_len,
+                              int(dec_sym.shape[0]), tile_syms, ss_max)
+    return tiles.reshape(ti.n_tiles, -1)[:, :tile_syms].reshape(-1)[:n_out]
 
 
 def _two_eb_f32(eb):
@@ -137,46 +158,47 @@ def fused_squeeze(shape):
 def fused_tile_rows(shape, tile_syms: int) -> int:
     """Rows per tile for the N-D fused kernels.
 
-    ~``tile_syms`` symbols per tile, rounded to whole rows; for 3-D the
+    ~``tile_syms`` symbols per tile, rounded to whole rows; beyond 2-D the
     row count must divide the plane height so no tile crosses a plane
     boundary (the row-carry reset happens between tiles).
     """
     plane_rows, cols = shape[-2], shape[-1]
     w = max(1, tile_syms // cols)
     w = min(w, plane_rows)
-    if len(shape) == 3:
+    if len(shape) >= 3:
         while plane_rows % w:
             w -= 1
     return w
 
 
+@partial(jax.jit, static_argnames=("max_len", "n_out", "tile_syms", "ss_max",
+                                   "radius", "shape", "out_dtype"))
 def decode_write_tiles_fused(units, dec_sym, dec_len, start_bits, end_bits,
                              offsets, total_bits, max_len: int, n_out: int,
                              tile_syms: int, ss_max: int, opos, oval, eb,
                              radius: int, lut_base=None, shape=None,
-                             out_dtype=jnp.float32, interpret: bool = True):
+                             out_dtype=jnp.float32):
     """Fused phase 4: tile decode + dequantize + inverse-Lorenzo epilogue.
 
     Same tile mapping as :func:`decode_write_tiles`; the kernel carries the
     decoded symbols through ``2*eb*(cumsum(code - radius))`` (outlier side
-    list ``opos``/``oval`` scattered in) without materializing the quant-code
-    array.  ``shape`` selects the 2-D/3-D epilogue (row/plane carries in VMEM
-    scratch); unit axes are squeezed first, so e.g. ``(1, n)`` still takes
-    the 1-D chained-carry kernel.  Returns reconstructed ``out_dtype[n_out]``
-    (flat, C-order).
+    list ``opos``/``oval`` written in) without materializing the quant-code
+    array.  ``shape`` selects the N-D epilogue (row carry in VMEM, the
+    carries of outer axes in HBM); unit axes are squeezed first, so e.g.
+    ``(1, n)`` still takes the 1-D chained-carry kernel.  Returns
+    reconstructed ``out_dtype[n_out]`` (flat, C-order).
     """
     sq = fused_squeeze(shape)
     out_dtype = jnp.dtype(out_dtype)
+    lut = C.pack_lut(dec_sym, dec_len)
+    side = C.side_list(opos, oval)
     if sq is None:
-        rows, start_local, end_local, off_local, lut_tile = _tile_inputs(
-            units, start_bits, end_bits, offsets, total_bits, n_out,
-            tile_syms, ss_max, lut_base)
-        return _fus.decode_tiles_fused(
-            rows, start_local, end_local, off_local, lut_tile, dec_sym,
-            dec_len, jnp.asarray(opos, jnp.int32),
-            jnp.asarray(oval, jnp.int32), _two_eb_f32(eb), max_len,
-            tile_syms, ss_max, n_out, radius, out_dtype=out_dtype,
-            interpret=interpret)
+        ti = tile_inputs(units, start_bits, end_bits, offsets, total_bits,
+                         n_out, tile_syms, ss_max, lut_base, outlier_pos=opos)
+        out = _fus.decode_tiles_fused(
+            ti, lut, side, _two_eb_f32(eb), max_len, int(dec_sym.shape[0]),
+            tile_syms, ss_max, radius, out_dtype=out_dtype)
+        return out.reshape(ti.n_tiles, -1)[:, :tile_syms].reshape(-1)[:n_out]
     # N-D: re-tile along whole rows of the fastest axis.  The tile size
     # changes, so the lane budget must be re-derived for the new tile.
     from repro.core.huffman.pipeline import ss_max_for_tile
@@ -184,20 +206,30 @@ def decode_write_tiles_fused(units, dec_sym, dec_len, start_bits, end_bits,
     rows_per_tile = fused_tile_rows(sq, tile_syms)
     block = rows_per_tile * sq[-1]
     ss_max_nd = ss_max_for_tile(block, max_len)
-    rows, start_local, end_local, off_local, lut_tile = _tile_inputs(
-        units, start_bits, end_bits, offsets, total_bits, n_out, block,
-        ss_max_nd, lut_base)
-    return _fus.decode_tiles_fused_nd(
-        rows, start_local, end_local, off_local, lut_tile, dec_sym, dec_len,
-        jnp.asarray(opos, jnp.int32), jnp.asarray(oval, jnp.int32),
-        _two_eb_f32(eb), max_len, rows_per_tile, sq, ss_max_nd, radius,
-        out_dtype=out_dtype, interpret=interpret)
+    ti = tile_inputs(units, start_bits, end_bits, offsets, total_bits, n_out,
+                     block, ss_max_nd, lut_base, outlier_pos=opos)
+    out = _fus.decode_tiles_fused_nd(
+        ti, lut, side, _two_eb_f32(eb), max_len, int(dec_sym.shape[0]),
+        rows_per_tile, sq, ss_max_nd, radius, out_dtype=out_dtype)
+    return out.reshape(-1)[:n_out]
 
 
+def _epilogue_tiles(codes, n_tiles: int, block: int, opos):
+    """Zero-pad flat codes to ``n_tiles * block`` and find each tile's
+    side-list range."""
+    pad = n_tiles * block - codes.shape[0]
+    if pad:
+        codes = jnp.concatenate([codes, jnp.zeros(pad, codes.dtype)])
+    bases = jnp.arange(n_tiles + 1, dtype=jnp.int32) * block
+    r = C.outlier_ranges(opos, bases)
+    return codes, jnp.stack([r[:-1], r[1:]], axis=1)[:, None, :]
+
+
+@partial(jax.jit, static_argnames=("max_len", "n_out", "radius", "shape",
+                                   "out_dtype"))
 def decode_padded_fused(units, dec_sym, dec_len, start_abs, end_abs,
                         total_bits, max_len: int, n_out: int, opos, oval, eb,
-                        radius: int, shape=None, out_dtype=jnp.float32,
-                        interpret: bool = True):
+                        radius: int, shape=None, out_dtype=jnp.float32):
     """Fused baseline phase 4: padded decode + the standalone epilogue kernel.
 
     The padded layout + compaction keeps the original decoders' scattered-
@@ -207,53 +239,45 @@ def decode_padded_fused(units, dec_sym, dec_len, start_abs, end_abs,
     instead of two jnp passes.
     """
     codes, _ = decode_padded_compact(units, dec_sym, dec_len, start_abs,
-                                     end_abs, total_bits, max_len, n_out,
-                                     interpret=interpret)
+                                     end_abs, total_bits, max_len, n_out)
     out_dtype = jnp.dtype(out_dtype)
+    side = C.side_list(opos, oval)
     sq = fused_squeeze(shape)
     if sq is None:
-        block = 4096
-        pad = (-n_out) % block
-        if pad:
-            codes = jnp.concatenate([codes, jnp.zeros(pad, jnp.uint16)])
-        out = _fus.dequant_reconstruct(codes, jnp.asarray(opos, jnp.int32),
-                                       jnp.asarray(oval, jnp.int32),
-                                       _two_eb_f32(eb), radius,
-                                       out_dtype=out_dtype,
-                                       interpret=interpret)
-        return out[:n_out]
+        tr = _dec.tile_rows(4096)
+        block = tr * C.LANES
+        n_tiles = -(-n_out // block)
+        codes, ranges = _epilogue_tiles(codes, n_tiles, block, opos)
+        out = _fus.dequant_reconstruct(
+            codes.reshape(n_tiles, tr, C.LANES), ranges, side,
+            _two_eb_f32(eb), radius, out_dtype=out_dtype)
+        return out.reshape(-1)[:n_out]
     rows_per_tile = fused_tile_rows(sq, 4096)
     block = rows_per_tile * sq[-1]
-    pad = (-n_out) % block
-    if pad:
-        codes = jnp.concatenate([codes, jnp.zeros(pad, jnp.uint16)])
-    return _fus.dequant_reconstruct_nd(
-        codes, jnp.asarray(opos, jnp.int32), jnp.asarray(oval, jnp.int32),
-        _two_eb_f32(eb), radius, sq, rows_per_tile, out_dtype=out_dtype,
-        interpret=interpret)
+    n_tiles = -(-n_out // block)
+    codes, ranges = _epilogue_tiles(codes, n_tiles, block, opos)
+    out = _fus.dequant_reconstruct_nd(
+        codes.reshape(n_tiles, rows_per_tile, sq[-1]), ranges, side,
+        _two_eb_f32(eb), radius, sq, out_dtype=out_dtype)
+    return out.reshape(-1)[:n_out]
 
 
+@partial(jax.jit, static_argnames=("max_len", "n_out"))
 def decode_padded_compact(units, dec_sym, dec_len, start_abs, end_abs,
-                          total_bits, max_len: int, n_out: int,
-                          interpret: bool = True):
+                          total_bits, max_len: int, n_out: int):
     """Kernel-backed baseline phase 4 (padded layout + ops-level compaction).
 
     Reproduces the original decoders' scattered-write cost structure."""
     ids, start_local, end_local = _subseq_windows(start_abs, end_abs,
                                                   total_bits)
     n = ids.shape[0]
-    ss_block = _dec.DEFAULT_SS_BLOCK
-    pad = (-n) % ss_block
-    if pad:
-        z = jnp.zeros(pad, jnp.int32)
-        ids, start_local, end_local = (jnp.concatenate([ids, z]),
-                                       jnp.concatenate([start_local, z]),
-                                       jnp.concatenate([end_local, z]))
-    rows = C.gather_subseq_rows(jnp.asarray(units), ids)
+    ids, start_local, end_local = _lane_block(ids, start_local, end_local,
+                                              _dec.DEFAULT_BLOCK_ROWS)
+    rows = C.lane_rows(units, ids)
     padded, counts = _dec.decode_padded(rows, start_local, end_local,
-                                        dec_sym, dec_len, max_len,
-                                        interpret=interpret)
-    padded, counts = padded[:n], counts[:n]
+                                        C.pack_lut(dec_sym, dec_len), max_len,
+                                        int(dec_sym.shape[0]))
+    padded, counts = padded[:n], counts.reshape(-1)[:n]
     offsets = hd.output_offsets(counts)
     out_pos = jnp.arange(n_out, dtype=jnp.int32)
     owner = jnp.clip(jnp.searchsorted(offsets, out_pos, side="right") - 1,
@@ -262,36 +286,41 @@ def decode_padded_compact(units, dec_sym, dec_len, start_abs, end_abs,
     return padded[owner, jnp.clip(within, 0, C.MAX_SYMS - 1)], counts
 
 
+@partial(jax.jit, static_argnames=("n_subseq", "subseqs_per_seq", "max_len",
+                                   "early_exit"))
 def selfsync_sync(units, dec_sym, dec_len, total_bits, n_subseq: int,
                   subseqs_per_seq: int, max_len: int,
-                  early_exit: bool = True, interpret: bool = True):
+                  early_exit: bool = True):
     """Kernel-backed sync discovery: intra-sequence kernel + inter-sequence
-    head chaining (phases 1+2).  Returns (start_abs, counts, stats)."""
-    units = jnp.asarray(units)
-    n_seq = n_subseq // subseqs_per_seq
+    head chaining (phases 1+2).  Returns (start_abs, counts, rounds)."""
+    sps = subseqs_per_seq
     boundaries = jnp.arange(n_subseq, dtype=jnp.int32) * SUBSEQ_BITS
     ids = jnp.arange(n_subseq, dtype=jnp.int32)
-    rows = C.gather_subseq_rows(units, ids).reshape(
-        n_seq, subseqs_per_seq, C.ROW_UNITS)
     end_local = jnp.clip(
         jnp.minimum(boundaries + SUBSEQ_BITS, total_bits) - boundaries,
-        0, C.ROW_UNITS * 32).reshape(n_seq, subseqs_per_seq)
+        0, C.ROW_UNITS * 32)
+    ids, _, end_local = _lane_block(ids, ids, end_local, C.SUBLANES)
+    rows = C.lane_rows(units, ids)
+    is_head = (jnp.arange(end_local.size, dtype=jnp.int32) % sps) == 0
+    n_pad_seq = end_local.size // sps
 
     run = partial(_sync.selfsync_intra, rows, end_local=end_local,
-                  dec_sym=dec_sym, dec_len=dec_len, max_len=max_len,
-                  subseqs_per_seq=subseqs_per_seq, early_exit=early_exit,
-                  interpret=interpret)
+                  lut=C.pack_lut(dec_sym, dec_len), max_len=max_len,
+                  lut_size=int(dec_sym.shape[0]), subseqs_per_seq=sps,
+                  early_exit=early_exit)
 
     def one_pass(heads):
-        start, counts, landing, rounds = run(heads=heads)
+        lane_heads = jnp.where(is_head, jnp.repeat(heads, sps), 0)
+        start, counts, landing, rounds = run(
+            lane_heads.reshape(end_local.shape))
         # Landing of each sequence's last lane seeds the next sequence.
-        new_heads = jnp.concatenate(
-            [jnp.zeros((1,), jnp.int32), landing[:-1, -1] - 128])[:, None]
-        return start, counts, rounds, new_heads
+        last = landing.reshape(n_pad_seq, sps)[:, -1]
+        new_heads = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                     last[:-1] - 128])
+        return start, counts, rounds.reshape(n_pad_seq, sps)[:, 0], new_heads
 
-    heads = jnp.zeros((n_seq, 1), jnp.int32)
+    heads = jnp.zeros((n_pad_seq,), jnp.int32)
     start, counts, rounds, new_heads = one_pass(heads)
-    total_rounds = rounds
 
     def cond(state):
         heads, new_heads, *_ = state
@@ -303,54 +332,46 @@ def selfsync_sync(units, dec_sym, dec_len, total_bits, n_subseq: int,
         return heads, new_heads, start, counts, total_rounds + rounds
 
     _, _, start, counts, total_rounds = jax.lax.while_loop(
-        cond, body, (heads, new_heads, start, counts, total_rounds))
+        cond, body, (heads, new_heads, start, counts, rounds))
 
-    start_abs = boundaries + start.reshape(-1)
-    return start_abs, counts.reshape(-1), total_rounds
+    start_abs = boundaries + start.reshape(-1)[:n_subseq]
+    return start_abs, counts.reshape(-1)[:n_subseq], total_rounds
 
 
 # ---------------------------------------------------------------------------
 # Encode bit-pack (write-path phase 4)
 # ---------------------------------------------------------------------------
 
-DEFAULT_ENCODE_TILE_UNITS = 8
-
-
 @partial(jax.jit, static_argnames=("n_units_padded", "subseqs_per_seq",
-                                   "min_len", "tile_units", "interpret"))
+                                   "min_len"))
 def _encode_bitpack_padded(symbols, enc_code, enc_len, n_units_padded: int,
-                           subseqs_per_seq: int, min_len: int,
-                           tile_units: int, interpret: bool):
+                           subseqs_per_seq: int, min_len: int):
     """Traced body of :func:`encode_bitpack` (sizes fixed for jit)."""
     symbols = symbols.astype(jnp.int32)
     lens = jnp.asarray(enc_len)[symbols].astype(jnp.int32)
-    starts = jnp.cumsum(lens) - lens               # exclusive scan [N]
+    starts = he.prefix_sum(lens) - lens            # exclusive scan [N]
     codes = jnp.asarray(enc_code)[symbols].astype(jnp.uint32)
     total_bits = (starts[-1] + lens[-1]).astype(jnp.int32)
     n = symbols.shape[0]
 
-    # --- tile -> symbol mapping (mirrors the decode kernels' prep) -----
-    tile_bits = tile_units * 32
-    n_tiles = n_units_padded // tile_units
-    # Lane budget: starts inside the tile are >= min_len apart, plus the
-    # (at most one) codeword crossing in from the left.
-    sym_max = tile_bits // max(min_len, 1) + 2
-    tile_base = jnp.arange(n_tiles, dtype=jnp.int32) * tile_bits
-    s0 = jnp.clip(jnp.searchsorted(starts, tile_base, side="right") - 1,
-                  0, n - 1)
-    lane = jnp.arange(sym_max, dtype=jnp.int32)
-    idx_raw = s0[:, None] + lane[None, :]
-    idx = jnp.clip(idx_raw, 0, n - 1)
-    st = starts[idx]
-    ln = lens[idx]
-    overlaps = ((idx_raw < n)
-                & (st < tile_base[:, None] + tile_bits)
-                & (st + ln > tile_base[:, None]))
-    tile_len = jnp.where(overlaps, ln, 0)
-    tile_start = st - tile_base[:, None]
-    units = _enc.pack_tiles(codes[idx], tile_len, tile_start,
-                            n_units_padded, tile_units, sym_max,
-                            interpret=interpret)
+    # --- output row -> symbol window (mirrors the decode kernels' prep) --
+    n_rows = -(-n_units_padded // C.LANES)
+    row_base = jnp.arange(n_rows, dtype=jnp.int32) * _enc.ROW_BITS
+    # First symbol that can touch each row: the one crossing in from the
+    # left (or the first starting in it); its 8-row-aligned symbol row.
+    s0 = jnp.maximum(jnp.searchsorted(starts, row_base, side="right") - 1, 0)
+    row0 = (s0 // (C.SUBLANES * C.LANES) * C.SUBLANES).astype(jnp.int32)
+    sym_rows = C.round_up(-(-n // C.LANES), C.SUBLANES) + _enc.window_rows(
+        min_len)
+    pad = sym_rows * C.LANES - n
+
+    def rows(a):
+        return jnp.concatenate([a, jnp.zeros((pad,), a.dtype)]).reshape(
+            sym_rows, C.LANES)
+
+    units = _enc.pack_rows(row0[:, None, None], rows(codes), rows(lens),
+                           rows(starts), min_len)
+    units = units.reshape(-1)[:n_units_padded]
 
     gaps, counts, seq_counts = he.stream_metadata(
         starts, total_bits, n_units_padded, subseqs_per_seq)
@@ -362,15 +383,14 @@ def _encode_bitpack_padded(symbols, enc_code, enc_len, n_units_padded: int,
 
 
 def encode_bitpack(symbols, enc_code, enc_len, total_bits: int,
-                   subseqs_per_seq: int, min_len: int = 1,
-                   tile_units: int = DEFAULT_ENCODE_TILE_UNITS,
-                   interpret: bool = True) -> he.EncodedStream:
-    """Kernel-backed Huffman encode: per-tile prefix-sum bit placement.
+                   subseqs_per_seq: int, min_len: int = 1
+                   ) -> he.EncodedStream:
+    """Kernel-backed Huffman encode: per-row prefix-sum bit placement.
 
     ``total_bits`` is the exact payload size (the ``EncoderPlan`` derives
     it from the histogram, so the symbol array never round-trips to host);
-    ``min_len`` (the codebook's shortest codeword) bounds the static lane
-    budget.  Layout is bit-identical to ``core.huffman.encode.encode``.
+    ``min_len`` (the codebook's shortest codeword) bounds the static symbol
+    window.  Layout is bit-identical to ``core.huffman.encode.encode``.
     """
     symbols = jnp.asarray(symbols)
     if symbols.shape[0] == 0:
@@ -378,8 +398,7 @@ def encode_bitpack(symbols, enc_code, enc_len, total_bits: int,
     n_units_padded = he.units_for_bits(total_bits, subseqs_per_seq)
     return _encode_bitpack_padded(symbols, jnp.asarray(enc_code),
                                   jnp.asarray(enc_len), n_units_padded,
-                                  subseqs_per_seq, min_len, tile_units,
-                                  interpret)
+                                  subseqs_per_seq, min_len)
 
 
 # ---------------------------------------------------------------------------
@@ -389,40 +408,46 @@ def encode_bitpack(symbols, enc_code, enc_len, total_bits: int,
 histogram = _hist.histogram
 
 
-def lorenzo_quantize(x, eb, radius: int = 512, interpret: bool = True):
-    """N-D dual-quant Lorenzo via the 1-D kernel applied per axis.
-
-    For 1-D inputs this is a single kernel launch; N-D composes the exact
-    integer finite-difference per axis at the ops level (the round-to-lattice
-    happens once, inside the kernel, along the innermost axis pass).
-    """
-    shape = x.shape
-    flat = x.reshape(-1)
-    n = flat.shape[0]
-    block = 4096
-    pad = (-n) % block
+def _lorenzo_rows(flat, n: int):
+    """Zero-pad a flat signal to whole ``lorenzo.block_rows`` blocks as
+    ``(rows, 128)``."""
+    unit = _lor.block_rows(n) * C.LANES
+    pad = (-n) % unit or (0 if n else unit)
     if pad:
         flat = jnp.concatenate([flat, jnp.zeros(pad, flat.dtype)])
-    if len(shape) == 1:
-        codes, outlier, resid = _lor.quantize1d(flat, float(eb), radius=radius,
-                                                interpret=interpret)
-        return codes[:n], outlier[:n].astype(bool), resid[:n]
-    # N-D: lattice quantize via kernel's rounding on the flat view, then the
-    # exact multi-axis finite difference in jnp (integer, exact).
-    from repro.core.sz import lorenzo as _ref
-
-    return _ref.quantize(x, eb, radius=radius)
+    return flat.reshape(-1, C.LANES)
 
 
-def lorenzo_reconstruct(d, eb, shape=None, interpret: bool = True):
+@partial(jax.jit, static_argnames=("radius",))
+def lorenzo_quantize(x, eb, radius: int = 512):
+    """Dual-quant Lorenzo: the 1-D kernel for 1-D inputs.
+
+    N-D inputs take the jnp path (``core.sz.lorenzo.quantize``): the
+    multi-axis finite difference is exact integer work around one
+    round-to-lattice, and the codes are the same either way.
+    """
+    if x.ndim != 1:
+        from repro.core.sz import lorenzo as _ref
+
+        return _ref.quantize(x, eb, radius=radius)
+    n = x.shape[0]
+    flat = jnp.asarray(x, jnp.float32)
+    xprev = jnp.concatenate([jnp.zeros((1,), jnp.float32), flat[:-1]])
+    codes, outlier, resid = _lor.quantize1d(
+        _lorenzo_rows(flat, n), _lorenzo_rows(xprev, n), _two_eb_f32(eb),
+        radius=radius)
+    return (codes.reshape(-1)[:n], outlier.reshape(-1)[:n].astype(bool),
+            resid.reshape(-1)[:n])
+
+
+@partial(jax.jit, static_argnames=("shape",))
+def lorenzo_reconstruct(d, eb, shape=None):
     """Inverse Lorenzo; 1-D uses the chained-scan kernel."""
     if shape is None or len(shape) == 1:
         n = d.shape[0]
-        block = 4096
-        pad = (-n) % block
-        dd = jnp.concatenate([d, jnp.zeros(pad, d.dtype)]) if pad else d
-        out = _lor.reconstruct1d(dd, float(eb), interpret=interpret)
-        return out[:n]
+        out = _lor.reconstruct1d(
+            _lorenzo_rows(jnp.asarray(d, jnp.int32), n), _two_eb_f32(eb))
+        return out.reshape(-1)[:n]
     q = d.reshape(shape)
     for axis in range(len(shape)):
         q = jnp.cumsum(q, axis=axis)

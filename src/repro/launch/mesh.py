@@ -15,23 +15,11 @@ import jax
 import numpy as np
 
 
-def _auto_axis_kwargs(n_axes: int) -> dict:
-    """``axis_types`` kwarg for ``jax.make_mesh`` where supported.
-
-    ``jax.sharding.AxisType`` (and the ``axis_types=`` parameter) only exist
-    in newer jax; older versions behave as if every axis were ``Auto``, so
-    omitting the kwarg there is semantically identical.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_auto_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 class MeshCapacityError(ValueError):
@@ -69,7 +57,7 @@ def make_host_mesh(data: int | None = None, model: int = 1, *,
         # Full-host mesh: let make_mesh pick the device order (it optimizes
         # for the physical topology on real accelerators).
         return jax.make_mesh((data, model), ("data", "model"),
-                             **_auto_axis_kwargs(2))
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
     grid = np.array(devs[:data * model]).reshape(data, model)
     return jax.sharding.Mesh(grid, ("data", "model"))
 
@@ -89,8 +77,10 @@ def forced_host_devices_env(n: int, *, single_threaded: bool = False,
     """Environment for a subprocess that should see ``n`` host devices.
 
     ``XLA_FLAGS`` must be set before jax is imported, so multi-device CPU
-    tests and the sharded-restore benchmark run in subprocesses built with
-    this.  ``single_threaded`` additionally pins each device's compiled
+    tests and, on the CPU, the sharded-restore benchmark run in
+    subprocesses built with this.  It is for the CPU only: on a TPU a
+    child process could not reach the chip its parent holds.
+    ``single_threaded`` additionally pins each device's compiled
     executables to one thread, so wall-clock scaling across devices
     reflects device count rather than the host's intra-op thread pool.
     """

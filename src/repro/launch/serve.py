@@ -39,6 +39,7 @@ from repro.models import decode as D
 from repro.models import kvcache
 from repro.models import steps as S
 from repro.models import transformer as T
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -87,6 +88,7 @@ def main(argv=None):
                          "stats['pages_lost'] counts it")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = configs.get_config(args.arch)
     if args.reduced:

@@ -27,6 +27,7 @@ from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.models import steps as S
 from repro.models import transformer as T
 from repro.optim import adamw
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -54,6 +55,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--opt-int8", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = configs.get_config(args.arch)
     if args.reduced:

@@ -185,10 +185,11 @@ class TestFusedCodec:
         assert got.tobytes() == want.tobytes()
 
     def test_nd_tensor_falls_back(self):
-        """2-D/3-D are fused-eligible now; >3 non-unit axes still fall
-        back (the fused epilogue covers up to 3-D Lorenzo)."""
+        """Tensors of any rank are fused-eligible; rows wider than
+        ``FUSED_MAX_COLS`` still fall back (a tile stages whole rows)."""
         codec = Codec(CodecConfig(fused=True))
-        c = codec.compress(smooth_field((5, 4, 6, 10), seed=23))
+        c = codec.compress(smooth_field((2, compressor.FUSED_MAX_COLS + 1),
+                                        seed=23))
         codec.backend.reset_stats()
         got = np.asarray(codec.decompress(c))
         assert codec.stats["fused_fallbacks"] == 1
@@ -221,12 +222,13 @@ class TestFusedCodec:
         """A fused batch decodes eligible (1-D/2-D) tensors through the
         fused dispatch and the rest through the class-merged two-pass path,
         in order, bit-exact, one recorded fallback per ineligible (here
-        4-D) tensor."""
+        wide-row) tensor."""
         codec = Codec(CodecConfig(fused=True))
+        wide = compressor.FUSED_MAX_COLS + 1
         cs = [codec.compress(smooth_field((3000,), seed=31)),
-              codec.compress(smooth_field((4, 5, 5, 20), seed=32)),
+              codec.compress(smooth_field((2, wide), seed=32)),
               codec.compress(smooth_field((20, 25), seed=33)),
-              codec.compress(smooth_field((3, 6, 6, 25), seed=34))]
+              codec.compress(smooth_field((3, wide), seed=34))]
         codec.backend.reset_stats()
         outs = codec.decompress_batch(cs)
         assert codec.stats["fused_fallbacks"] == 2

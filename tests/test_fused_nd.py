@@ -31,7 +31,7 @@ from repro.core.sz import compressor as sz
 from repro.data.pipeline import smooth_field
 
 try:
-    from hypothesis import given, settings
+    from hypothesis import example, given, settings
     from hypothesis import strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:                       # container has no hypothesis
@@ -56,7 +56,8 @@ def _field(shape, dtype, seed):
     x = np.asarray(smooth_field(shape, seed=seed)).copy()
     flat = x.reshape(-1)
     rng = np.random.default_rng(seed + 1000)
-    idx = rng.choice(flat.size, size=max(4, flat.size // 400), replace=False)
+    idx = rng.choice(flat.size, size=min(flat.size, max(4, flat.size // 400)),
+                     replace=False)
     flat[idx] += np.float32(40.0) * (x.max() - x.min() + 1.0) * \
         rng.choice(np.asarray([-1.0, 1.0], np.float32), size=idx.size)
     return jnp.asarray(x).astype(dtype)
@@ -112,6 +113,24 @@ class TestFusedNdParity:
             x, np.float32).astype(np.float64)).max()
         assert err <= c.eb_effective
 
+    @pytest.mark.parametrize("strategy", ["tile", "padded"])
+    @pytest.mark.parametrize("shape", [(3, 4, 10, 40), (2, 3, 2, 8, 130)])
+    def test_beyond_3d_kernel_parity(self, shape, strategy):
+        """4-D and 5-D tensors decode fused on the kernels (one HBM carry
+        per outer axis), bit-exact with two-pass ref."""
+        x = _field(shape, jnp.float32, seed=11)
+        cfg = CodecConfig(eb=1e-4, radius=RADIUS, tile_syms=TILE_SYMS)
+        c = Codec(cfg).compress(x)
+        fus = Codec(cfg.replace(backend="pallas", strategy=strategy,
+                                fused=True))
+        fus.backend.reset_stats()
+        got = np.asarray(fus.decompress(c))
+        assert fus.stats["fused_fallbacks"] == 0
+        assert fus.stats["fused_dispatches"] == 1
+        want = np.asarray(Codec(cfg).decompress(c))
+        assert got.tobytes() == want.tobytes()
+        assert np.abs(got - np.asarray(x)).max() <= c.eb_effective
+
     def test_unit_axes_squeeze(self):
         """(1, R, C) reconstructs through the 2-D epilogue, bit-exact."""
         x = _field((1, 56, 72), jnp.float32, seed=5)
@@ -153,10 +172,10 @@ class TestFusedNdEligibility:
         assert "tuned" in sz.fused_unsupported_reason(ok, be, "gap", "tuned")
         assert "oracle" in sz.fused_unsupported_reason(
             ok, be, "naive_ref", "tile")
-        # 4 non-unit axes: beyond the 3-D epilogue.
+        # 4 non-unit axes: served by the outer carries of the N-D epilogue.
         codec = Codec(CodecConfig(eb=1e-3, radius=RADIUS))
         c4 = codec.compress(smooth_field((4, 5, 6, 8), seed=2))
-        assert "4-D" in sz.fused_unsupported_reason(c4, be, "gap", "tile")
+        assert sz.fused_unsupported_reason(c4, be, "gap", "tile") is None
         # float64 stays two-pass (synthesized: jnp truncates f64 inputs at
         # compress, so a real f64 Compressed never arises on this build).
         import dataclasses
@@ -166,8 +185,9 @@ class TestFusedNdEligibility:
             c64, be, "gap", "tile")
 
     def test_width_bounds(self):
-        """Tensors past the VMEM row/plane provisioning report a reason
-        (without paying for a huge compress: synthesize the metadata)."""
+        """Rows past the VMEM row provisioning report a reason; planes have
+        no bound, since the plane carry lives in HBM (without paying for a
+        huge compress: synthesize the metadata)."""
         be = hp.get_backend("ref")
         base = _make_case(2, "f32", "rel", 1e-4)[1]
         import dataclasses
@@ -176,10 +196,8 @@ class TestFusedNdEligibility:
             base, shape=(4, sz.FUSED_MAX_COLS + 1))
         assert "fastest axis" in sz.fused_unsupported_reason(
             wide, be, "gap", "tile")
-        deep = dataclasses.replace(
-            base, shape=(4, 2048, (sz.FUSED_MAX_PLANE // 2048) + 1))
-        assert "plane" in sz.fused_unsupported_reason(
-            deep, be, "gap", "tile")
+        deep = dataclasses.replace(base, shape=(4, 2048, 2049))
+        assert sz.fused_unsupported_reason(deep, be, "gap", "tile") is None
 
 
 class TestFallbackAccounting:
@@ -188,11 +206,12 @@ class TestFallbackAccounting:
 
     def _mixed(self):
         codec = Codec(CodecConfig(eb=1e-3, radius=RADIUS))
+        wide = sz.FUSED_MAX_COLS + 1
         return codec, [
             codec.compress(smooth_field((3000,), seed=41)),       # eligible
-            codec.compress(smooth_field((4, 5, 6, 10), seed=42)),  # 4-D
+            codec.compress(smooth_field((2, wide), seed=42)),     # wide rows
             codec.compress(smooth_field((20, 25), seed=43)),      # eligible
-            codec.compress(smooth_field((3, 6, 6, 25), seed=44)),  # 4-D
+            codec.compress(smooth_field((3, wide), seed=44)),     # wide rows
         ]
 
     @pytest.mark.parametrize("strategy", ["tile", "padded"])
@@ -287,6 +306,9 @@ if HAVE_HYPOTHESIS:
         dims=st.lists(st.integers(3, 40), min_size=3, max_size=3),
         seed=st.integers(0, 2**16),
     )
+    # A tensor smaller than the spike count of ``_field`` (found by the
+    # property search; pinned so it replays without the example database).
+    @example(ndim=1, dtype_key="f32", strategy="tile", dims=[3, 3, 3], seed=0)
     def test_fused_parity_property(ndim, dtype_key, strategy, dims, seed):
         shape = tuple(dims[:ndim])
         x = _field(shape, DTYPES[dtype_key], seed)
