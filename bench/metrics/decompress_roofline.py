@@ -1,0 +1,20 @@
+"""Share of the HBM roofline over the decompress spans.
+
+Least time: (compressed payload read + decoded values written) over the
+chip's HBM bandwidth (``bench/work.py``).  Measured time: the busy time of
+every device program inside the benchmark's decompress spans, summed over
+the devices.
+"""
+
+from bench import trace as T
+from bench import work
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    spans = run.trace.spans_of("decompress")
+    busy = sum(T.busy_ns(run.trace, d, spans) for d in run.trace.devices)
+    nbytes = sum(work.bytes_moved(o.work) for o in run.ops_of("decompress"))
+    return work.roofline_percent(nbytes, busy / 1e9,
+                                 run.peaks["hbm_bytes_per_s"])
