@@ -31,7 +31,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma list: tableI,tableII,tableIV,tableV,"
                          "fig2,fig4,batch,store,fused,serving,sharded,"
-                         "arch,roofline")
+                         "arch")
     ap.add_argument("--record", default=None, metavar="BENCH_tag.json",
                     help="write rows to a JSON trajectory file")
     ap.add_argument("--compare", default=None, metavar="BENCH_old.json",
@@ -53,9 +53,8 @@ def main() -> None:
     from benchmarks import (arch_step, batch_decode, compression_ratio,
                             cr_sensitivity, decode_throughput,
                             decoder_phases, e2e_decompression,
-                            encode_throughput, fused_decode, roofline,
-                            serving_load, sharded_restore, shmem_tuning,
-                            store_throughput)
+                            encode_throughput, fused_decode, serving_load,
+                            sharded_restore, shmem_tuning, store_throughput)
 
     suites = [
         ("tableV", decode_throughput.run),
@@ -71,7 +70,6 @@ def main() -> None:
         ("serving", serving_load.run),
         ("sharded", sharded_restore.run),
         ("arch", arch_step.run),
-        ("roofline", roofline.run),
     ]
     all_rows = []
     regressions = []

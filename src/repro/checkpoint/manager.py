@@ -21,6 +21,7 @@ Everything else is a raw ``.npy`` with its checksum recorded in
 from __future__ import annotations
 
 import concurrent.futures as futures
+import contextvars
 import json
 import os
 import shutil
@@ -35,6 +36,7 @@ from repro.core.huffman import pipeline as hp
 from repro.core.sz.compressor import Compressed
 from repro.distributed.restore import ShardedRestorer
 from repro.distributed.shards import ShardedWriter
+from repro.runtime import trace
 from repro.store import Archive, ArchiveWriter, StoreError
 
 ARCHIVE_NAME = "archive.szt"
@@ -181,16 +183,20 @@ class CheckpointManager:
         ``shard_count`` per-host ``.szt`` shards (default: one per
         process) that ``restore(mesh=...)`` decodes in parallel, directly
         into the target shardings."""
-        if self._pool is not None:
-            self.wait()
-            params = jax.tree.map(np.asarray, params)  # snapshot now
-            opt_state = jax.tree.map(np.asarray, opt_state) if opt_state else None
-            self._pending = self._pool.submit(
-                self._save_sync, step, params, opt_state, extra, mesh,
-                shardings, opt_shardings, shard_count)
-            return
-        self._save_sync(step, params, opt_state, extra, mesh, shardings,
-                        opt_shardings, shard_count)
+        with trace.operation("ckpt.save", step=step):
+            if self._pool is not None:
+                self.wait()
+                with trace.span("ckpt.snapshot"):          # snapshot now
+                    params = jax.tree.map(trace.to_host, params)
+                    opt_state = (jax.tree.map(trace.to_host, opt_state)
+                                 if opt_state else None)
+                self._pending = self._pool.submit(
+                    contextvars.copy_context().run, self._save_sync, step,
+                    params, opt_state, extra, mesh, shardings, opt_shardings,
+                    shard_count)
+                return
+            self._save_sync(step, params, opt_state, extra, mesh, shardings,
+                            opt_shardings, shard_count)
 
     def _save_sync(self, step, params, opt_state, extra, mesh=None,
                    shardings=None, opt_shardings=None, shard_count=None):
@@ -209,8 +215,9 @@ class CheckpointManager:
         writer = sw = None
         try:
             for tname, tree in trees.items():
-                flat = {key: np.asarray(leaf)
-                        for key, leaf in _flatten(tree).items()}
+                with trace.span("ckpt.snapshot", tree=tname):
+                    flat = {key: trace.to_host(leaf)
+                            for key, leaf in _flatten(tree).items()}
                 flat_specs = (_flatten(spec_trees[tname])
                               if spec_trees[tname] is not None else None)
                 if self.codec is not None and not sharded:
@@ -251,7 +258,8 @@ class CheckpointManager:
                             "dtype": str(np.dtype(leaf.dtype))}
                     else:
                         path = os.path.join(tmp, fname + ".npy")
-                        with open(path, "wb") as f:
+                        with trace.span("ckpt.save_raw", name=fname), \
+                                open(path, "wb") as f:
                             tee = _CrcTee(f)
                             np.save(tee, leaf, allow_pickle=False)
                         manifest["entries"][fname] = {
@@ -264,17 +272,18 @@ class CheckpointManager:
             if sw is not None:
                 sw.abort()
             raise
-        if writer is not None:
-            for fname, crc in writer.checksums().items():
-                manifest["entries"][fname]["checksum"] = crc
-            writer.close()
-        if sw is not None:
-            sw.close()
-            manifest["version"] = MANIFEST_VERSION
-            manifest["n_shards"] = sw.n_shards
-        _write_json_atomic(os.path.join(tmp, "manifest.json"), manifest)
-        shutil.rmtree(final, ignore_errors=True)
-        os.rename(tmp, final)
+        with trace.span("ckpt.publish"):
+            if writer is not None:
+                for fname, crc in writer.checksums().items():
+                    manifest["entries"][fname]["checksum"] = crc
+                writer.close()
+            if sw is not None:
+                sw.close()
+                manifest["version"] = MANIFEST_VERSION
+                manifest["n_shards"] = sw.n_shards
+            _write_json_atomic(os.path.join(tmp, "manifest.json"), manifest)
+            shutil.rmtree(final, ignore_errors=True)
+            os.rename(tmp, final)
 
     def wait(self):
         if self._pending is not None:
@@ -303,7 +312,7 @@ class CheckpointManager:
         ``CheckpointIntegrityError``, never a raw parse error."""
         mpath = os.path.join(d, "manifest.json")
         try:
-            with open(mpath) as f:
+            with trace.span("ckpt.manifest", step=step), open(mpath) as f:
                 manifest = json.load(f)
         except FileNotFoundError as e:
             raise CheckpointIntegrityError(
@@ -451,7 +460,7 @@ class CheckpointManager:
                 f"step {step}: raw shard {fname!r} failed its checksum "
                 f"(corrupt or truncated file)")
         try:
-            return jnp.asarray(np.load(path, allow_pickle=False))
+            return trace.to_device(np.load(path, allow_pickle=False))
         except (ValueError, OSError, EOFError) as e:
             raise CheckpointIntegrityError(
                 f"step {step}: raw shard {fname!r} is unreadable: {e}") from e
@@ -495,6 +504,11 @@ class CheckpointManager:
           replaced by zeros of their recorded shape/dtype so the restored
           tree keeps its structure.
         """
+        with trace.operation("ckpt.restore"):
+            return self._restore(step, policy, mesh, shardings,
+                                 opt_shardings)
+
+    def _restore(self, step, policy, mesh, shardings, opt_shardings):
         pol = self._read_codec.recovery_policy(policy)
         fallback_from: list = []
         if step is None:
@@ -556,7 +570,8 @@ class CheckpointManager:
                         continue
             else:
                 try:
-                    arr = self._restore_raw(d, step, fname, meta)
+                    with trace.span("ckpt.load_raw", name=fname):
+                        arr = self._restore_raw(d, step, fname, meta)
                 except CheckpointIntegrityError as e:
                     if pol.on_error == "raise":
                         raise

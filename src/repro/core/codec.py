@@ -44,6 +44,7 @@ from repro.core.huffman import pipeline as hp
 from repro.core.sz import compressor, lorenzo
 from repro.core.sz.compressor import Compressed
 from repro.runtime import fault_tolerance as ft
+from repro.runtime import trace
 
 VALID_MODES = ("rel", "abs")
 VALID_METHODS = ("gap", "selfsync", "naive_ref")
@@ -205,7 +206,8 @@ class Codec:
 
     @property
     def stats(self) -> dict:
-        """Merged backend dispatch counters + plan-cache hit counters.
+        """Merged backend dispatch counters, plan-cache hit counters and
+        the process-wide transfer and compile counters.
 
         Backend handles are process-wide singletons per name, so the
         dispatch/plan-build counters are shared by every codec on the same
@@ -214,14 +216,17 @@ class Codec:
         The encode backend's write-path counters (``encode_dispatches``,
         ``encode_fallbacks``, ``encoder_plan_builds``) merge in under their
         own keys -- disjoint from the decode counters by construction.
+        ``h2d_bytes``, ``d2h_bytes``, ``compiles`` and ``compile_ms`` come
+        from ``runtime/trace.py`` (docs/api.md, "Tracing").
         """
         return {**self.backend.stats, **self.encode_backend.stats,
-                **self.plan_cache.stats}
+                **self.plan_cache.stats, **trace.counters()}
 
     def reset_stats(self):
         self.backend.reset_stats()
         self.encode_backend.reset_stats()
         self.plan_cache.reset_stats()
+        trace.reset_counters()
 
     def recovery_policy(self, policy=None) -> ft.RecoveryPolicy:
         """This codec's ``RecoveryPolicy``; ``policy`` (a string or a
@@ -232,10 +237,11 @@ class Codec:
 
     def compress(self, x) -> Compressed:
         c = self.config
-        return compressor.compress(x, eb=c.eb, mode=c.mode, radius=c.radius,
-                                   max_len=c.max_len,
-                                   subseqs_per_seq=c.subseqs_per_seq,
-                                   encode_backend=self.encode_backend)
+        with trace.span("codec.compress"):
+            return compressor.compress(x, eb=c.eb, mode=c.mode,
+                                       radius=c.radius, max_len=c.max_len,
+                                       subseqs_per_seq=c.subseqs_per_seq,
+                                       encode_backend=self.encode_backend)
 
     def build_plan(self, stream, codebook) -> hp.DecoderPlan:
         """Phase 1-3 plan under this codec's (method, backend, t_high)."""

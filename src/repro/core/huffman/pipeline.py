@@ -42,6 +42,7 @@ from repro.core.huffman import codebook as _cb
 from repro.core.huffman import decode as hd
 from repro.core.huffman.bits import SUBSEQ_BITS, UNIT_BITS
 from repro.core.huffman.encode import EncodedStream
+from repro.runtime import trace
 
 
 class DecodeGuardError(RuntimeError):
@@ -154,13 +155,13 @@ def make_plan(stream, seq_counts, subseqs_per_seq: int,
     """
     del stream
     classes, hist, order = _classify_sequences(
-        jnp.asarray(seq_counts), subseqs_per_seq, t_high)
+        trace.to_device(seq_counts), subseqs_per_seq, t_high)
     class_start = np.zeros(t_high + 3, np.int32)
-    class_start[1:] = np.cumsum(np.asarray(hist))
+    class_start[1:] = np.cumsum(trace.to_host(hist))
     return ClassPlan(
         t_high=t_high,
-        classes=np.asarray(classes),
-        seq_order=np.asarray(order),
+        classes=trace.to_host(classes),
+        seq_order=trace.to_host(order),
         class_start=class_start,
         tile_syms={c: tile_for_class(c, t_high) for c in range(1, t_high + 2)},
     )
@@ -502,7 +503,7 @@ def get_encode_backend(backend: "str | EncodeBackend") -> EncodeBackend:
 def _host_quantize(x, abs_eb, radius):
     from repro.core.sz import lorenzo  # lazy: core.sz imports this module
 
-    return lorenzo.quantize_host(np.asarray(x), abs_eb, radius=radius)
+    return lorenzo.quantize_host(trace.to_host(x), abs_eb, radius=radius)
 
 
 def _jnp_quantize(x, abs_eb, radius):
@@ -626,12 +627,12 @@ def build_encoder_plan(freq, max_len: int, subseqs_per_seq: int,
 
     be = get_encode_backend(backend)
     be.bump("encoder_plan_builds")
-    freq_np = np.asarray(freq, dtype=np.int64)
+    freq_np = trace.to_host(freq, np.int64)
     book = cb.build_codebook(freq_np, max_len=max_len)
     total_bits = int((freq_np * book.enc_len.astype(np.int64)).sum())
     return EncoderPlan(codebook=book,
-                       enc_code=jnp.asarray(book.enc_code),
-                       enc_len=jnp.asarray(book.enc_len),
+                       enc_code=trace.to_device(book.enc_code),
+                       enc_len=trace.to_device(book.enc_len),
                        total_bits=total_bits,
                        subseqs_per_seq=subseqs_per_seq)
 
@@ -664,8 +665,8 @@ class DecodeLuts:
 
 
 def _as_luts(codebook) -> DecodeLuts:
-    return DecodeLuts(dec_sym=jnp.asarray(codebook.dec_sym),
-                      dec_len=jnp.asarray(codebook.dec_len),
+    return DecodeLuts(dec_sym=trace.to_device(codebook.dec_sym),
+                      dec_len=trace.to_device(codebook.dec_len),
                       max_len=int(codebook.max_len))
 
 
@@ -720,7 +721,12 @@ def build_plan(stream: EncodedStream, codebook, method: str = "gap",
     every build is counted in ``backend.stats["plan_builds"]`` so tests
     and benchmarks can assert cache hits.
     """
-    be = get_backend(backend)
+    with trace.span("plan.build"):
+        return _build_plan(stream, codebook, method, get_backend(backend),
+                           t_high, early_exit)
+
+
+def _build_plan(stream, codebook, method, be, t_high, early_exit):
     be.bump("plan_builds")
     problems = _cb.validate_codebook(codebook)
     if problems:
@@ -731,34 +737,37 @@ def build_plan(stream: EncodedStream, codebook, method: str = "gap",
     units = jnp.asarray(stream.units)
     n_subseq = stream.n_subseq
     sps = stream.subseqs_per_seq
-    boundaries, ends = _window_bounds(n_subseq)
 
-    if method == "gap":
-        # A valid gap never exceeds SUBSEQ_BITS (the encoder stores the
-        # offset of the first codeword start inside a 128-bit window, or
-        # the in-window distance to end-of-stream).  ``_gap_starts`` clamps
-        # anything larger -- a corrupt gap array -- so sync starts stay
-        # inside the window their counts were computed for; count the
-        # containment.
-        if stream.gaps.size and int(np.asarray(stream.gaps).max(
-                initial=0)) > SUBSEQ_BITS:
-            be.bump("decode_guard_trips")
-        starts = _gap_starts(boundaries, stream.gaps)
-        counts = be.count_fn(units, luts.dec_sym, luts.dec_len, starts, ends,
-                             stream.total_bits, luts.max_len)
-    elif method == "selfsync":
-        starts, counts = be.sync_fn(units, luts.dec_sym, luts.dec_len,
-                                    stream.total_bits, n_subseq, sps,
-                                    luts.max_len, early_exit=early_exit)
-    else:
-        raise ValueError(f"unknown method {method!r}; valid methods: "
-                         f"{list(VALID_PLAN_METHODS)}")
+    with trace.span("plan.count"):
+        boundaries, ends = _window_bounds(n_subseq)
+        if method == "gap":
+            # A valid gap never exceeds SUBSEQ_BITS (the encoder stores the
+            # offset of the first codeword start inside a 128-bit window,
+            # or the in-window distance to end-of-stream).  ``_gap_starts``
+            # clamps anything larger -- a corrupt gap array -- so sync
+            # starts stay inside the window their counts were computed
+            # for; count the containment.
+            if stream.gaps.size and int(trace.to_host(stream.gaps).max(
+                    initial=0)) > SUBSEQ_BITS:
+                be.bump("decode_guard_trips")
+            starts = _gap_starts(boundaries, stream.gaps)
+            counts = be.count_fn(units, luts.dec_sym, luts.dec_len, starts,
+                                 ends, stream.total_bits, luts.max_len)
+        elif method == "selfsync":
+            starts, counts = be.sync_fn(units, luts.dec_sym, luts.dec_len,
+                                        stream.total_bits, n_subseq, sps,
+                                        luts.max_len, early_exit=early_exit)
+        else:
+            raise ValueError(f"unknown method {method!r}; valid methods: "
+                             f"{list(VALID_PLAN_METHODS)}")
 
-    counts = jnp.asarray(counts)
-    offsets = hd.output_offsets(counts)
-    seq_counts = np.asarray(counts).reshape(-1, sps).sum(
-        axis=1, dtype=np.int64)
-    classes = make_plan(None, seq_counts, sps, t_high)
+    with trace.span("plan.offsets"):
+        counts = jnp.asarray(counts)
+        offsets = hd.output_offsets(counts)
+        seq_counts = trace.to_host(counts).reshape(-1, sps).sum(
+            axis=1, dtype=np.int64)
+    with trace.span("plan.classify"):
+        classes = make_plan(None, seq_counts, sps, t_high)
     return DecoderPlan(method=method, start_bits=jnp.asarray(starts),
                        end_bits=ends, counts=counts, offsets=offsets,
                        seq_counts=seq_counts, classes=classes,

@@ -20,6 +20,7 @@ from repro.core.huffman import decode as hd
 from repro.core.huffman import encode as he
 from repro.core.huffman import pipeline as hp
 from repro.core.sz import lorenzo
+from repro.runtime import trace
 
 DEFAULT_EB = 1e-3
 
@@ -163,8 +164,9 @@ def compress(
     backend cannot serve fall back to "ref", counted in
     ``stats["encode_fallbacks"]``.
     """
-    x = jnp.asarray(x)
-    span, max_abs = (float(v) for v in _value_stats(x))
+    x = trace.to_device(x)
+    with trace.span("compress.stats"):
+        span, max_abs = (float(trace.to_host(v)) for v in _value_stats(x))
     if mode == "rel":
         rng = span if span > 0 else 1.0
         abs_eb = eb * rng
@@ -184,37 +186,44 @@ def compress(
         if np.round(max_abs / (2.0 * abs_eb)) >= 2**31 - 1:
             raise ValueError(
                 "error bound too small for int32 lattice; increase eb")
-        codes, outlier, resid = ebe.quantize_fn(x, abs_eb, radius)
-        codes_flat = codes.reshape(-1)
-        csum, n_outliers = _outlier_prefix(outlier)
-        # One scalar sync sizes the side list; the gather stays on device.
-        m_pad = _outlier_m_pad(int(n_outliers))
-        pos_pad, val_pad = _gather_outliers(csum, resid, m_pad)
-        freq = ebe.hist_fn(codes_flat, 2 * radius)
+        with trace.span("compress.outliers"):
+            codes, outlier, resid = ebe.quantize_fn(x, abs_eb, radius)
+            codes_flat = codes.reshape(-1)
+            csum, n_outliers = _outlier_prefix(outlier)
+            # One scalar sync sizes the side list; the gather stays on
+            # device.
+            m_pad = _outlier_m_pad(int(trace.to_host(n_outliers)))
+            pos_pad, val_pad = _gather_outliers(csum, resid, m_pad)
     else:
-        codes_np, outlier, resid = ebe.quantize_fn(x, abs_eb, radius)
-        codes_flat = codes_np.reshape(-1)
+        with trace.span("compress.outliers"):
+            codes_np, outlier, resid = ebe.quantize_fn(x, abs_eb, radius)
+            codes_flat = codes_np.reshape(-1)
 
-        # Outlier side list (exact residuals), padded to power-of-two length.
-        pos = np.nonzero(np.asarray(outlier).reshape(-1))[0].astype(np.int32)
-        vals = np.asarray(resid).reshape(-1)[pos].astype(np.int32)
-        m_pad = _outlier_m_pad(len(pos))
-        pos_pad = np.full(m_pad, -1, np.int32)
-        val_pad = np.zeros(m_pad, np.int32)
-        pos_pad[: len(pos)] = pos
-        val_pad[: len(pos)] = vals
-        freq = ebe.hist_fn(codes_flat, 2 * radius)
+            # Outlier side list (exact residuals), padded to power-of-two
+            # length.
+            pos = np.nonzero(np.asarray(outlier).reshape(-1))[0].astype(
+                np.int32)
+            vals = np.asarray(resid).reshape(-1)[pos].astype(np.int32)
+            m_pad = _outlier_m_pad(len(pos))
+            pos_pad = np.full(m_pad, -1, np.int32)
+            val_pad = np.zeros(m_pad, np.int32)
+            pos_pad[: len(pos)] = pos
+            val_pad[: len(pos)] = vals
 
     # Histogram -> codebook (host package-merge) -> bit-pack dispatch.
-    plan = hp.build_encoder_plan(freq, max_len=max_len,
-                                 subseqs_per_seq=subseqs_per_seq, backend=ebe)
-    stream = hp.encode_with_plan(codes_flat, plan, backend=ebe)
+    with trace.span("compress.codebook"):
+        freq = ebe.hist_fn(codes_flat, 2 * radius)
+        plan = hp.build_encoder_plan(freq, max_len=max_len,
+                                     subseqs_per_seq=subseqs_per_seq,
+                                     backend=ebe)
+    with trace.span("compress.pack"):
+        stream = hp.encode_with_plan(codes_flat, plan, backend=ebe)
 
     return Compressed(
         stream=stream,
         codebook=plan.codebook,
-        outlier_pos=jnp.asarray(pos_pad),
-        outlier_val=jnp.asarray(val_pad),
+        outlier_pos=trace.to_device(pos_pad),
+        outlier_val=trace.to_device(val_pad),
         shape=tuple(x.shape),
         dtype=np.dtype(str(x.dtype)),
         eb=abs_eb,
@@ -388,6 +397,13 @@ def decompress_batch(
     ``stats["fused_fallbacks"]`` exactly once.  Output order and bit
     patterns are unchanged either way.
     """
+    cs = list(cs)
+    with trace.span("decode.dispatch", n=len(cs)):
+        return _decompress_batch(cs, method, backend, strategy, t_high,
+                                 plans, fused)
+
+
+def _decompress_batch(cs, method, backend, strategy, t_high, plans, fused):
     if not cs:
         return []
     if plans is None and method in hp.VALID_PLAN_METHODS:

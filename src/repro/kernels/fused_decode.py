@@ -281,11 +281,12 @@ def _outputs(out_shape, out_block, chunks, rows_pad, tiles_per_plane,
     return specs, shapes
 
 
-def _fused_call(ti, lut, side, two_eb, *, kernel, grid_out, out_block,
+def _fused_call(ti, lut, side, two_eb, *, name, kernel, grid_out, out_block,
                 stage_shape, carries, outer=(0, 0, 0, ())):
     out_specs, out_shape = _outputs(grid_out, out_block, *outer)
     return pl.pallas_call(
         kernel,
+        name=name,
         grid=(ti.n_tiles,),
         in_specs=_dec.tile_in_specs(ti, lut) + _side_specs(),
         out_specs=out_specs,
@@ -322,7 +323,7 @@ def decode_tiles_fused(ti: _dec.TileInputs, lut, side, two_eb, max_len: int,
         tile=tile_syms, ss_max=ss_max, cols=C.LANES, rows=None,
         radius=radius, tiles_per_plane=0, levels=(), out_dtype=out_dtype)
     return _fused_call(
-        ti, lut, side, two_eb, kernel=kernel,
+        ti, lut, side, two_eb, name="decode_tiles_fused", kernel=kernel,
         grid_out=jax.ShapeDtypeStruct((ti.n_tiles, tr, C.LANES), out_dtype),
         out_block=(1, tr, C.LANES), stage_shape=(1, tr, C.LANES),
         carries=[pltpu.VMEM((1, C.LANES), jnp.int32)])
@@ -352,7 +353,7 @@ def decode_tiles_fused_nd(ti: _dec.TileInputs, lut, side, two_eb,
         rows=rows_per_tile, radius=radius, tiles_per_plane=tpp,
         levels=levels, out_dtype=out_dtype)
     return _fused_call(
-        ti, lut, side, two_eb, kernel=kernel,
+        ti, lut, side, two_eb, name="decode_tiles_fused_nd", kernel=kernel,
         grid_out=jax.ShapeDtypeStruct((ti.n_tiles, rows_per_tile, cols),
                                       out_dtype),
         out_block=(1, rows_per_tile, cols),
@@ -394,13 +395,14 @@ def _epilogue_kernel(codes_ref, meta_ref, teb_ref, opos, oval, *refs, cols,
         _write_rows(out_ref, x, rows, cols)
 
 
-def _epilogue_call(codes, ranges, side, two_eb, *, kernel, stage_shape,
-                   carries, out_dtype, outer=(0, 0, 0, ())):
+def _epilogue_call(codes, ranges, side, two_eb, *, name, kernel,
+                   stage_shape, carries, out_dtype, outer=(0, 0, 0, ())):
     n_tiles, r, c = codes.shape
     out_specs, out_shape = _outputs(
         jax.ShapeDtypeStruct(codes.shape, out_dtype), (1, r, c), *outer)
     return pl.pallas_call(
         kernel,
+        name=name,
         grid=(n_tiles,),
         in_specs=[pl.BlockSpec((1, r, c), lambda t: (t, 0, 0)),
                   pl.BlockSpec((1, 1, 2), lambda t: (t, 0, 0),
@@ -429,7 +431,8 @@ def dequant_reconstruct(codes, ranges, side, two_eb, radius: int,
     kernel = functools.partial(_epilogue_kernel, cols=C.LANES, rows=None,
                                radius=radius, tiles_per_plane=0, levels=(),
                                out_dtype=out_dtype)
-    return _epilogue_call(codes, ranges, side, two_eb, kernel=kernel,
+    return _epilogue_call(codes, ranges, side, two_eb,
+                          name="dequant_reconstruct", kernel=kernel,
                           stage_shape=(1,) + codes.shape[1:],
                           carries=[pltpu.VMEM((1, C.LANES), jnp.int32)],
                           out_dtype=out_dtype)
@@ -451,7 +454,8 @@ def dequant_reconstruct_nd(codes, ranges, side, two_eb, radius: int,
                                rows=rows_per_tile, radius=radius,
                                tiles_per_plane=tpp, levels=levels,
                                out_dtype=out_dtype)
-    return _epilogue_call(codes, ranges, side, two_eb, kernel=kernel,
+    return _epilogue_call(codes, ranges, side, two_eb,
+                          name="dequant_reconstruct_nd", kernel=kernel,
                           stage_shape=(chunks, rows_pad, C.LANES),
                           carries=_carry_scratch(chunks, rows_pad, levels),
                           out_dtype=out_dtype,

@@ -58,6 +58,7 @@ def histogram(x, nbins: int):
     x = x.reshape(-1, C.LANES)
     return pl.pallas_call(
         functools.partial(_hist_kernel, nbins=nbins),
+        name="histogram",
         grid=(x.shape[0] // br,),
         in_specs=[pl.BlockSpec((br, C.LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -131,6 +131,7 @@ def count_subseq(rows, start_local, end_local, lut, max_len: int,
     out = jax.ShapeDtypeStruct(start_local.shape, jnp.int32)
     return pl.pallas_call(
         functools.partial(_count_kernel, max_len=max_len, lut_size=lut_size),
+        name="count_subseq",
         grid=(n_rows // block_rows,),
         in_specs=[rows_spec, lane_spec, lane_spec,
                   pl.BlockSpec(lut.shape, lambda b: (0, 0))],
@@ -173,6 +174,7 @@ def decode_tiles(ti: TileInputs, lut, max_len: int, lut_size: int,
     return pl.pallas_call(
         functools.partial(_decode_tiles_kernel, max_len=max_len,
                           lut_size=lut_size, tile=tile_syms, ss_max=ss_max),
+        name="decode_tiles",
         grid=(ti.n_tiles,),
         in_specs=tile_in_specs(ti, lut),
         out_specs=pl.BlockSpec(block, lambda t: (t, 0, 0)),
@@ -211,6 +213,7 @@ def decode_padded(rows, start_local, end_local, lut, max_len: int,
     return pl.pallas_call(
         functools.partial(_decode_padded_kernel, max_len=max_len,
                           lut_size=lut_size),
+        name="decode_padded",
         grid=(n_rows // block_rows,),
         in_specs=[rows_spec, lane_spec, lane_spec,
                   pl.BlockSpec(lut.shape, lambda b: (0, 0))],

@@ -100,6 +100,7 @@ def pack_rows(row0, codes, lens, starts, min_len: int):
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
         _pack_kernel,
+        name="pack_rows",
         grid=(n_rows,),
         in_specs=[pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0),
                                memory_space=pltpu.SMEM), hbm, hbm, hbm],

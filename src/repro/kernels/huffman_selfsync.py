@@ -107,6 +107,7 @@ def selfsync_intra(rows, heads, end_local, lut, max_len: int, lut_size: int,
         functools.partial(_selfsync_kernel, max_len=max_len,
                           lut_size=lut_size, early_exit=early_exit,
                           subseqs_per_seq=subseqs_per_seq),
+        name="selfsync_intra",
         grid=(n_rows // block_rows,),
         in_specs=[pl.BlockSpec((C.ROW_UNITS, block_rows, C.LANES),
                                lambda b: (0, b, 0)),

@@ -61,6 +61,7 @@ def quantize1d(x, xprev, two_eb, radius: int = 512):
     spec = pl.BlockSpec((br, C.LANES), lambda i: (i, 0))
     return pl.pallas_call(
         functools.partial(_quant_kernel, radius=radius),
+        name="quantize1d",
         grid=(rows // br,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), spec, spec],
         out_specs=[spec, spec, spec],
@@ -96,6 +97,7 @@ def reconstruct1d(d, two_eb):
     spec = pl.BlockSpec((br, C.LANES), lambda i: (i, 0))
     return pl.pallas_call(
         _recon_kernel,
+        name="reconstruct1d",
         grid=(rows // br,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), spec],
         out_specs=spec,

@@ -131,7 +131,7 @@ class ModelConfig:
 
 
 def count_params(cfg: ModelConfig) -> int:
-    """Analytic parameter count (used for MODEL_FLOPS in the roofline)."""
+    """Analytic parameter count."""
     d, dh = cfg.d_model, cfg.head_dim
     nq, nkv = cfg.n_heads, cfg.n_kv_heads
 

@@ -20,6 +20,7 @@ plans come from the ``PlanCache`` keyed by chunk digest; a warm cache
 from __future__ import annotations
 
 import concurrent.futures as futures
+import contextvars
 import mmap
 import os
 
@@ -33,6 +34,7 @@ from repro.core.huffman import pipeline as hp
 from repro.core.huffman.encode import EncodedStream
 from repro.core.sz import compressor as sz
 from repro.runtime import fault_tolerance as ft
+from repro.runtime import trace
 from repro.store import format as F
 
 DEFAULT_GROUP_CHUNKS = 8
@@ -61,8 +63,9 @@ class Archive:
                       "io_retries": 0}
         # Transient IO errors (OSError) while opening retry per the codec's
         # recovery policy; corruption (StoreError) never retries.
-        ft.with_retries(self._open, self.codec.recovery_policy(),
-                        on_retry=self._count_retry)
+        with trace.span("archive.open"):
+            ft.with_retries(self._open, self.codec.recovery_policy(),
+                            on_retry=self._count_retry)
 
     def _count_retry(self, attempt, exc):
         self.stats["io_retries"] += 1
@@ -148,13 +151,17 @@ class Archive:
         Host-side only -- this is the half the prefetch thread runs.
         """
         rec = self.chunk(name)
+        with trace.span("archive.stage", name=name):
+            return self._stage(rec, validate)
+
+    def _stage(self, rec: F.ChunkRecord, validate: bool):
         units = self._blob(rec.units, np.uint32)
         gaps = self._blob(rec.gaps, np.uint8)
         opos = self._blob(rec.outlier_pos, np.int32)
         oval = self._blob(rec.outlier_val, np.int32)
         if validate and F.crc32_arrays(units, gaps, opos, oval) != rec.crc32:
             raise F.StoreCorruptError(
-                f"{self.path}: chunk {name!r} payload checksum mismatch "
+                f"{self.path}: chunk {rec.name!r} payload checksum mismatch "
                 f"(corrupt or truncated archive)")
         # Copy out of the map before device placement: on the CPU backend
         # jax aliases numpy buffers zero-copy, which would pin the mmap (and
@@ -164,7 +171,7 @@ class Archive:
         book = self.codebook(rec.codebook)
         n_subseq = gaps.shape[0]
         stream = EncodedStream(
-            units=jnp.asarray(units), gaps=jnp.asarray(gaps),
+            units=trace.to_device(units), gaps=trace.to_device(gaps),
             # Ground-truth counts are not stored: the decoder recomputes
             # them on device in phase 1 (or loads a cached plan).
             counts=jnp.zeros((n_subseq,), jnp.int32),
@@ -175,7 +182,8 @@ class Archive:
             subseqs_per_seq=rec.subseqs_per_seq)
         c = sz.Compressed(
             stream=stream, codebook=book,
-            outlier_pos=jnp.asarray(opos), outlier_val=jnp.asarray(oval),
+            outlier_pos=trace.to_device(opos),
+            outlier_val=trace.to_device(oval),
             shape=rec.shape, dtype=np.dtype(rec.dtype), eb=rec.eb,
             radius=rec.radius, rel_range=rec.rel_range, max_abs=rec.max_abs)
         # Seed the content digest from the index record so a direct
@@ -284,12 +292,21 @@ class Archive:
         pool = (futures.ThreadPoolExecutor(
             1, thread_name_prefix="szt-prefetch")
             if prefetch and len(groups) > 1 else None)
+
+        def submit(group):
+            # The prefetch thread's spans name the operation they serve.
+            return pool.submit(contextvars.copy_context().run, load, group)
+
         try:
-            nxt = pool.submit(load, groups[0]) if pool else None
+            nxt = submit(groups[0]) if pool else None
             for gi, group in enumerate(groups):
-                blobs = nxt.result() if pool else load(group)
+                if pool:
+                    with trace.span("archive.wait"):
+                        blobs = nxt.result()
+                else:
+                    blobs = load(group)
                 if pool and gi + 1 < len(groups):
-                    nxt = pool.submit(load, groups[gi + 1])
+                    nxt = submit(groups[gi + 1])
 
                 failed = {}                      # name -> named exception
                 ok_names, ok_cs, ok_plans = [], [], []
@@ -329,10 +346,13 @@ class Archive:
 
                 for name in group:
                     if name in outs:
-                        out = jnp.asarray(
-                            outs[name],
-                            jnp.dtype(self.chunk(name).orig_dtype))
-                        yield name, np.asarray(out) if as_numpy else out
+                        with trace.span("archive.cast"):
+                            out = jnp.asarray(
+                                outs[name],
+                                jnp.dtype(self.chunk(name).orig_dtype))
+                            if as_numpy:
+                                out = trace.to_host(out)
+                        yield name, out
                         continue
                     sub = self._recover(name, failed[name], pol, on_error)
                     if sub is not None:
@@ -343,7 +363,8 @@ class Archive:
 
     def read_all(self, names=None, **kwargs) -> dict:
         """Decode ``names`` (default: every chunk) into {name: array}."""
-        return dict(self.iter_decode(names, **kwargs))
+        with trace.operation("archive.read_all"):
+            return dict(self.iter_decode(names, **kwargs))
 
     def read_tensor(self, name: str, **kwargs):
         return self.read_all([name], **kwargs)[name]

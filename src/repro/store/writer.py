@@ -18,6 +18,7 @@ import os
 import numpy as np
 
 from repro.core.huffman.pipeline import T_HIGH_DEFAULT
+from repro.runtime import trace
 from repro.store import format as F
 
 
@@ -91,13 +92,17 @@ class ArchiveWriter:
         if name in self._names:
             raise F.StoreError(f"duplicate chunk name {name!r}")
         self._names.add(name)
-        c = compressed
+        with trace.span("archive.add", name=name):
+            self._add(name, compressed, orig_dtype)
+
+    def _add(self, name, c, orig_dtype):
         cb_digest = self._add_codebook(c.codebook)
 
-        units = np.asarray(c.stream.units, np.uint32)
-        gaps = np.asarray(c.stream.gaps, np.uint8)
-        opos = np.asarray(c.outlier_pos, np.int32)
-        oval = np.asarray(c.outlier_val, np.int32)
+        with trace.span("archive.fetch"):       # waits on the encode
+            units = trace.to_host(c.stream.units, np.uint32)
+            gaps = trace.to_host(c.stream.gaps, np.uint8)
+            opos = trace.to_host(c.outlier_pos, np.int32)
+            oval = trace.to_host(c.outlier_val, np.int32)
         # Integrity CRC covers the stored (padded) blobs exactly as written;
         # the *digest* hashes only content (valid outlier prefix), so the
         # plan-cache key is independent of pad width / producing backend.
@@ -105,8 +110,8 @@ class ArchiveWriter:
         content_crc = F.payload_crc(units, gaps, opos, oval)
 
         units_ref = self._write_blob(units)
-        total_bits = int(c.stream.total_bits)
-        n_symbols = int(c.stream.n_symbols)
+        total_bits = int(trace.to_host(c.stream.total_bits))
+        n_symbols = int(trace.to_host(c.stream.n_symbols))
         sps = int(c.stream.subseqs_per_seq)
         self._chunks.append(F.ChunkRecord(
             name=name,
