@@ -85,7 +85,11 @@ class CodecConfig:
       strategy         "tuned" (per-CR-class tiles, Alg. 2) | "tile"
                        (fixed tiles, Alg. 1) | "padded" (baseline layout)
       t_high           highest non-overflow CR class of the tuner
-      tile_syms        tile size for the fixed-"tile" strategy
+      tile_syms        ``None`` (default): the "tile" strategy sizes its
+                       tiles from each plan's counts so a tile's windows
+                       fill one vector register of decoder lanes
+                       (``pipeline.tile_geometry``); an int pins the tile
+                       size (the paper's fixed-buffer Alg. 1)
       fused            decode→dequantize→reconstruct in ONE dispatch: phase
                        4 emits reconstructed values directly, never writing
                        the uint16 quant-code array to HBM.  Bit-exact with
@@ -124,7 +128,7 @@ class CodecConfig:
     backend: str = "ref"
     strategy: str = "tile"
     t_high: int = hp.T_HIGH_DEFAULT
-    tile_syms: int = hp.DEFAULT_TILE_SYMS
+    tile_syms: "int | None" = None
     fused: bool = False
     plan_cache_size: int = 4096
     recovery: str = "raise"
@@ -156,7 +160,7 @@ class CodecConfig:
             raise ValueError(f"radius must be >= 2, got {self.radius}")
         if not (1 <= self.max_len <= 24):
             raise ValueError(f"max_len must be in [1, 24], got {self.max_len}")
-        if self.tile_syms < 1:
+        if self.tile_syms is not None and self.tile_syms < 1:
             raise ValueError(f"tile_syms must be >= 1, got {self.tile_syms}")
         if self.subseqs_per_seq < 1:
             raise ValueError("subseqs_per_seq must be >= 1, got "
@@ -298,7 +302,8 @@ class Codec:
                                            backend=self.backend,
                                            strategy=c.strategy,
                                            t_high=c.t_high, plans=plans,
-                                           fused=c.fused)
+                                           fused=c.fused,
+                                           tile_syms=c.tile_syms)
 
     def decode(self, stream, codebook, n_out: int, *, plan=None,
                early_exit: bool = True):

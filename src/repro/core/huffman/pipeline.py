@@ -11,7 +11,8 @@ that sequence into two layers so every consumer (``core/sz/compressor``,
                     offsets, CR classes, per-class tile sizes.
     decode()        phase 4 through a named *backend*; strategies:
                     "tuned"  per-CR-class tile decode (paper Alg. 1 + 2),
-                    "tile"   fixed-tile staged decode (paper Alg. 1),
+                    "tile"   staged decode over tiles sized from the plan's
+                             counts (paper Alg. 1; ``tile_geometry``),
                     "padded" padded-layout baseline (the original decoders'
                              uncoalesced-write cost structure).
     decode_batch()  class-merged decode of MANY tensors: sequences of equal
@@ -61,7 +62,8 @@ class DecodeGuardError(RuntimeError):
 T_HIGH_DEFAULT = 8          # paper's V100 value; VMEM budget gives the same
 OVERFLOW_TILE = 3584        # paper: optimal buffer for CR > T_high on V100
 SYMBOL_BYTES = 2
-DEFAULT_TILE_SYMS = 4096
+DEFAULT_TILE_SYMS = 4096    # the "tile" strategy's floor; pinned when set
+VREG_LANES = 8 * 128        # decoder lanes in one int32 vector register
 
 #: Decode-write strategies accepted by ``decode`` (and ``CodecConfig``).
 VALID_STRATEGIES = ("tuned", "tile", "padded")
@@ -82,6 +84,110 @@ def ss_max_for_tile(tile_syms: int, max_len: int) -> int:
     """
     min_starts = (SUBSEQ_BITS - max_len) // max_len + 1
     return tile_syms // min_starts + 2
+
+
+# ---------------------------------------------------------------------------
+# "tile" strategy geometry
+# ---------------------------------------------------------------------------
+
+
+def fused_squeeze(shape):
+    """Canonical fused-path view of ``shape``: unit axes dropped.
+
+    Cumsum along a unit axis is the identity, so reconstruction over the
+    squeezed shape is bitwise the reconstruction over the full shape.  Both
+    the eligibility check (``compressor.fused_unsupported_reason``) and the
+    kernel dispatch must agree on this rule.  ``None`` for 1-D.
+    """
+    if shape is None:
+        return None
+    sq = tuple(int(s) for s in shape if s != 1)
+    return sq if len(sq) > 1 else None
+
+
+def fused_tile_rows(shape, tile_syms: int) -> int:
+    """Rows per tile for the N-D fused kernels.
+
+    ~``tile_syms`` symbols per tile, rounded to whole rows; beyond 2-D the
+    row count must divide the plane height so no tile crosses a plane
+    boundary (the row-carry reset happens between tiles).
+    """
+    plane_rows, cols = shape[-2], shape[-1]
+    w = max(1, tile_syms // cols)
+    w = min(w, plane_rows)
+    if len(shape) >= 3:
+        while plane_rows % w:
+            w -= 1
+    return w
+
+
+@dataclasses.dataclass(frozen=True)
+class TileGeometry:
+    """Tiles of one "tile"-strategy decode and the lanes each provisions.
+
+    ``tile`` symbols per tile (whole rows of the fastest axis for N-D
+    fused output), ``lanes`` the lane budget (``ss_max``) of every tile;
+    ``steps`` tiles (grid steps) and ``windows`` the sum over tiles of the
+    windows each overlaps, the work that fills those lanes.
+    """
+
+    tile: int
+    lanes: int
+    steps: int
+    windows: int
+
+
+def tile_geometry(shape, offsets: np.ndarray, n_out: int, max_len: int,
+                  tile_syms: "int | None" = None) -> TileGeometry:
+    """Tile size and lane budget of a "tile" decode of ``n_out`` symbols.
+
+    ``shape`` is the squeezed N-D output shape of a fused decode
+    (``fused_squeeze``), ``None`` for flat tiles; ``offsets`` the plan's
+    host prefix sum of per-window symbol counts.
+
+    An explicit ``tile_syms`` pins the tile (whole rows for N-D, as
+    ``fused_tile_rows`` rounds it) and the worst-case lane bound
+    ``ss_max_for_tile``.  Otherwise the tile is sized so that the windows
+    it overlaps fill one vector register of decoder lanes: about
+    ``VREG_LANES`` windows' worth of symbols at the stream's mean symbols
+    per window, in whole 1024-symbol units (flat) or whole rows that
+    divide the plane height (beyond 2-D; tiles never cross a plane).  The
+    lane budget is the exact largest span of that geometry, computed as
+    the kernels map tiles to windows, rounded up to whole 128-lane rows;
+    where it exceeds ``VREG_LANES`` the next smaller admissible tile is
+    tried, never below the ``DEFAULT_TILE_SYMS`` geometry.  A budget under
+    the true span would drop symbols, so exactness is what keeps decode
+    correct.
+    """
+    floor = DEFAULT_TILE_SYMS if tile_syms is None else int(tile_syms)
+    if shape is None:
+        unit, rows = VREG_LANES, None
+    else:
+        unit, rows = shape[-1], shape[-2]
+    floor_tile = floor if rows is None else fused_tile_rows(shape,
+                                                            floor) * unit
+
+    def geometry(tile, lanes=None):
+        spans = _tile_spans(offsets, tile, n_out)
+        if lanes is None:
+            lanes = -(-int(spans.max()) // 128) * 128
+        return TileGeometry(tile=tile, lanes=lanes, steps=int(spans.size),
+                            windows=int(spans.sum()))
+
+    if tile_syms is not None:
+        return geometry(floor_tile, ss_max_for_tile(floor_tile, max_len))
+
+    n_windows = max(int(np.count_nonzero(np.diff(offsets))), 1)
+    target = VREG_LANES * n_out // n_windows // unit
+    lo = -(-floor_tile // unit)
+    hi = min(target, -(-n_out // unit) if rows is None else rows)
+    cands = [w for w in range(hi, lo, -1)
+             if rows is None or len(shape) < 3 or rows % w == 0]
+    for w in cands:
+        g = geometry(w * unit)
+        if g.lanes <= VREG_LANES:
+            return g
+    return geometry(floor_tile)
 
 
 # ---------------------------------------------------------------------------
@@ -239,6 +345,8 @@ class DecodeBackend:
     fused_padded_fn: "Callable | None" = None
     stats: dict = dataclasses.field(
         default_factory=lambda: {"decode_write_dispatches": 0,
+                                 "decode_steps": 0,
+                                 "decode_windows": 0,
                                  "plan_builds": 0,
                                  "fused_dispatches": 0,
                                  "fused_fallbacks": 0,
@@ -263,18 +371,32 @@ class DecodeBackend:
             for k in self.stats:
                 self.stats[k] = 0
 
+    def count_tiles(self, steps: int, windows: int):
+        """Count a tile decode's grid steps and the windows its tiles
+        overlap (host arithmetic, no device sync): ``windows / (steps *
+        VREG_LANES)`` is the share of a vector register of decoder lanes
+        that carries a real window."""
+        with self._stats_lock:
+            self.stats["decode_steps"] += steps
+            self.stats["decode_windows"] += windows
+
     # Counted dispatch wrappers: every phase-4 launch goes through these.
-    def decode_tiles(self, *args, **kwargs):
+    # ``geometry`` (a ``TileGeometry``) also counts the grid's steps.
+    def decode_tiles(self, *args, geometry=None, **kwargs):
         self.bump("decode_write_dispatches")
+        if geometry is not None:
+            self.count_tiles(geometry.steps, geometry.windows)
         return self.tiles_fn(*args, **kwargs)
 
     def decode_padded(self, *args, **kwargs):
         self.bump("decode_write_dispatches")
         return self.padded_fn(*args, **kwargs)
 
-    def decode_tiles_fused(self, *args, **kwargs):
+    def decode_tiles_fused(self, *args, geometry=None, **kwargs):
         self.bump("decode_write_dispatches")
         self.bump("fused_dispatches")
+        if geometry is not None:
+            self.count_tiles(geometry.steps, geometry.windows)
         return self.fused_tiles_fn(*args, **kwargs)
 
     def decode_padded_fused(self, *args, **kwargs):
@@ -679,6 +801,7 @@ class DecoderPlan:
     end_bits: jnp.ndarray       # int32[n_subseq] absolute window ends
     counts: jnp.ndarray         # int32[n_subseq] codeword starts per window
     offsets: jnp.ndarray        # int32[n_subseq+1] exclusive prefix sum
+    host_offsets: np.ndarray    # int64[n_subseq+1] the same, on the host
     seq_counts: np.ndarray      # int64[n_seq] symbols per sequence
     classes: ClassPlan          # per-CR-class dispatch plan
     subseqs_per_seq: int
@@ -764,14 +887,22 @@ def _build_plan(stream, codebook, method, be, t_high, early_exit):
     with trace.span("plan.offsets"):
         counts = jnp.asarray(counts)
         offsets = hd.output_offsets(counts)
-        seq_counts = trace.to_host(counts).reshape(-1, sps).sum(
-            axis=1, dtype=np.int64)
+        counts_np = trace.to_host(counts)
+        seq_counts = counts_np.reshape(-1, sps).sum(axis=1, dtype=np.int64)
     with trace.span("plan.classify"):
         classes = make_plan(None, seq_counts, sps, t_high)
     return DecoderPlan(method=method, start_bits=jnp.asarray(starts),
                        end_bits=ends, counts=counts, offsets=offsets,
+                       host_offsets=_host_offsets(counts_np),
                        seq_counts=seq_counts, classes=classes,
                        subseqs_per_seq=sps, t_high=t_high)
+
+
+def _host_offsets(counts: np.ndarray) -> np.ndarray:
+    """Exclusive prefix sum of host counts (int64, one entry more)."""
+    out = np.zeros(counts.shape[0] + 1, np.int64)
+    np.cumsum(counts, out=out[1:])
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -786,21 +917,22 @@ def _pad_pow2(n: int, lo: int = 8) -> int:
     return p
 
 
-def _max_tile_span(offsets: np.ndarray, tile_syms: int, n_sym: int) -> int:
-    """Most subsequences any ``tile_syms``-symbol output tile overlaps.
+def _tile_spans(offsets: np.ndarray, tile_syms: int,
+                n_sym: int) -> np.ndarray:
+    """Subsequences each ``tile_syms``-symbol output tile overlaps.
 
     ``offsets`` is the exclusive prefix sum over the gathered subsequences
     (host int64).  Matches the ``searchsorted`` tile->subsequence mapping of
-    the decode-write kernels.
+    the decode-write kernels (``ops.tile_inputs``).
     """
     if n_sym <= 0 or offsets.shape[0] <= 1:
-        return 1
+        return np.ones(1, np.int64)
     n_tiles = (n_sym + tile_syms - 1) // tile_syms
     base = np.arange(n_tiles, dtype=np.int64) * tile_syms
     s0 = np.searchsorted(offsets, base, side="right") - 1
     last = np.minimum(base + tile_syms, n_sym) - 1
     s1 = np.maximum(np.searchsorted(offsets, last, side="right") - 1, s0)
-    return int((s1 - s0 + 1).max())
+    return s1 - s0 + 1
 
 
 def _class_dispatch(tiles_fn, units, dec_sym, dec_len, max_len: int,
@@ -883,7 +1015,8 @@ def _class_dispatch(tiles_fn, units, dec_sym, dec_len, max_len: int,
         # tensor) partial subsequence at a stream tail can carry fewer, so
         # also bound by the worst ACTUAL span any tile needs.
         ss_max = max(ss_max_for_tile(tile, max_len),
-                     _max_tile_span(offs_np[:1 + n_ss], tile, class_n) + 2)
+                     int(_tile_spans(offs_np[:1 + n_ss], tile,
+                                     class_n).max()) + 2)
         ss_max = -(-ss_max // 8) * 8   # round up: bounds jit-cache variants
 
         kwargs = {}
@@ -935,7 +1068,7 @@ def decode(stream: EncodedStream, codebook, n_out: int, *,
            plan: "DecoderPlan | None" = None,
            backend: "str | DecodeBackend" = "ref",
            method: str = "gap", strategy: str = "tile",
-           tile_syms: int = DEFAULT_TILE_SYMS,
+           tile_syms: "int | None" = None,
            t_high: int = T_HIGH_DEFAULT,
            early_exit: bool = True,
            transform: "OutputTransform | None" = None) -> jnp.ndarray:
@@ -955,9 +1088,10 @@ def decode(stream: EncodedStream, codebook, n_out: int, *,
       method:    sync discovery when building the plan: "gap" (gap array)
                  or "selfsync" (see ``VALID_PLAN_METHODS``).
       strategy:  decode-write variant: "tuned" (per-CR-class tiles, paper
-                 Alg. 2), "tile" (fixed ``tile_syms`` tiles, Alg. 1), or
+                 Alg. 2), "tile" (one tile size per decode, Alg. 1), or
                  "padded" (the original decoders' baseline layout).
-      tile_syms: tile size for the fixed-"tile" strategy.
+      tile_syms: ``None`` sizes the "tile" strategy's tiles from the plan's
+                 counts (``tile_geometry``); an int pins them.
       t_high:    highest non-overflow CR class when building the plan.
       early_exit: the self-sync ``__all_sync`` early-exit toggle.
       transform: optional ``OutputTransform``.  When attached, phase 4 runs
@@ -1001,12 +1135,13 @@ def decode(stream: EncodedStream, codebook, n_out: int, *,
                 plan.end_bits, stream.total_bits, luts.max_len, n_out,
                 t.outlier_pos, t.outlier_val, t.eb, t.radius,
                 shape=t_shape, out_dtype=t_dtype)
-        ss_max = ss_max_for_tile(tile_syms, luts.max_len)
+        g = tile_geometry(fused_squeeze(t_shape), plan.host_offsets, n_out,
+                          luts.max_len, tile_syms)
         return be.decode_tiles_fused(
             units, luts.dec_sym, luts.dec_len, plan.start_bits,
             plan.end_bits, plan.offsets, stream.total_bits, luts.max_len,
-            n_out, tile_syms, ss_max, t.outlier_pos, t.outlier_val, t.eb,
-            t.radius, shape=t_shape, out_dtype=t_dtype)
+            n_out, g.tile, g.lanes, t.outlier_pos, t.outlier_val, t.eb,
+            t.radius, shape=t_shape, out_dtype=t_dtype, geometry=g)
     if transform is not None and strategy in VALID_STRATEGIES:
         raise ValueError(
             f"fused decode (transform=) supports strategies 'tile' and "
@@ -1019,11 +1154,12 @@ def decode(stream: EncodedStream, codebook, n_out: int, *,
                                 plan.start_bits, plan.end_bits,
                                 stream.total_bits, luts.max_len, n_out)
     if strategy == "tile":
-        ss_max = ss_max_for_tile(tile_syms, luts.max_len)
+        g = tile_geometry(None, plan.host_offsets, n_out, luts.max_len,
+                          tile_syms)
         return be.decode_tiles(units, luts.dec_sym, luts.dec_len,
                                plan.start_bits, plan.end_bits, plan.offsets,
                                stream.total_bits, luts.max_len, n_out,
-                               tile_syms, ss_max)
+                               g.tile, g.lanes, geometry=g)
     if strategy == "tuned":
         meta = _tensor_meta(plan, n_out)
         return _class_dispatch(be.decode_tiles, units, luts.dec_sym,
@@ -1048,13 +1184,14 @@ def execute_tuned(stream: EncodedStream, dec_sym, dec_len, max_len: int,
     counts = jnp.asarray(counts)
     sps = stream.subseqs_per_seq
     n_subseq = stream.n_subseq
-    seq_counts = np.asarray(counts).reshape(-1, sps).sum(axis=1,
-                                                         dtype=np.int64)
+    counts_np = np.asarray(counts)
+    seq_counts = counts_np.reshape(-1, sps).sum(axis=1, dtype=np.int64)
     classes = make_plan(None, seq_counts, sps, t_high)
     ends = jnp.arange(n_subseq, dtype=jnp.int32) * SUBSEQ_BITS + SUBSEQ_BITS
     plan = DecoderPlan(method="gap", start_bits=jnp.asarray(start_bits),
                        end_bits=ends, counts=counts,
                        offsets=hd.output_offsets(counts),
+                       host_offsets=_host_offsets(counts_np),
                        seq_counts=seq_counts, classes=classes,
                        subseqs_per_seq=sps, t_high=t_high)
     meta = _tensor_meta(plan, n_out)

@@ -259,7 +259,7 @@ def fused_unsupported_reason(c: Compressed, backend, method: str,
     """Why the fused decode path cannot serve this tensor (None = it can).
 
     The fused epilogue covers N-D inverse Lorenzo (unit axes are squeezed
-    first -- ``kernels/ops.py:fused_squeeze``) over float32, bfloat16 and
+    first -- ``pipeline.fused_squeeze``) over float32, bfloat16 and
     float16 outputs (``FUSED_DTYPES``).  Still falling back to the
     two-pass path (recorded in ``stats["fused_fallbacks"]``): other
     dtypes, rows wider than ``FUSED_MAX_COLS``, the sequential oracle
@@ -309,7 +309,7 @@ def _guard_symbol_count(c: Compressed, plan, backend) -> None:
 def decompress(
     c: Compressed,
     method: str = "gap",
-    tile_syms: int = hp.DEFAULT_TILE_SYMS,
+    tile_syms: "int | None" = None,
     *,
     backend: "str | hp.DecodeBackend" = "ref",
     strategy: str = "tile",
@@ -325,8 +325,10 @@ def decompress(
     directly.  Decoding goes through ``core.huffman.pipeline.decode``:
     ``backend`` in ``available_backends()`` selects the jnp reference or the
     Pallas kernels (compiled on a TPU, interpreted elsewhere), ``strategy``
-    in {"tuned", "tile", "padded"} selects the decode-write variant, and
-    ``plan`` may carry a prebuilt ``DecoderPlan``.
+    in {"tuned", "tile", "padded"} selects the decode-write variant,
+    ``tile_syms`` pins the "tile" strategy's tiles (``None`` sizes them
+    from the plan's counts, ``pipeline.tile_geometry``), and ``plan`` may
+    carry a prebuilt ``DecoderPlan``.
 
     ``fused=True`` requests the fused decode→dequantize→reconstruct path:
     phase 4 carries the decoded symbols straight through dequantization and
@@ -375,6 +377,7 @@ def decompress_batch(
     t_high: int = hp.T_HIGH_DEFAULT,
     plans: "list | None" = None,
     fused: bool = False,
+    tile_syms: "int | None" = None,
 ) -> list:
     """Decompress many tensors with class-batched decode dispatch.
 
@@ -391,7 +394,9 @@ def decompress_batch(
     tensors the fused path can serve (see :func:`fused_unsupported_reason`)
     decode one-by-one through the fused kernels under ``strategy`` (zero
     quant-code HBM round trip, but one dispatch chain per tensor); the
-    rest decode through the class-merged two-pass path.  Eligibility is
+    rest decode through the class-merged two-pass path.  ``tile_syms``
+    pins the fused "tile" decodes' tiles as in :func:`decompress` (``None``
+    sizes them from each plan's counts).  Eligibility is
     evaluated exactly ONCE per tensor here -- against the strategy that
     would actually run -- and every ineligible tensor bumps
     ``stats["fused_fallbacks"]`` exactly once.  Output order and bit
@@ -400,10 +405,11 @@ def decompress_batch(
     cs = list(cs)
     with trace.span("decode.dispatch", n=len(cs)):
         return _decompress_batch(cs, method, backend, strategy, t_high,
-                                 plans, fused)
+                                 plans, fused, tile_syms)
 
 
-def _decompress_batch(cs, method, backend, strategy, t_high, plans, fused):
+def _decompress_batch(cs, method, backend, strategy, t_high, plans, fused,
+                      tile_syms):
     if not cs:
         return []
     if plans is None and method in hp.VALID_PLAN_METHODS:
@@ -421,7 +427,8 @@ def _decompress_batch(cs, method, backend, strategy, t_high, plans, fused):
                 out = hp.decode(c.stream, c.codebook, c.n_symbols,
                                 plan=plans[i] if plans else None,
                                 method=method, backend=be,
-                                strategy=strategy, t_high=t_high,
+                                strategy=strategy, tile_syms=tile_syms,
+                                t_high=t_high,
                                 transform=_fused_transform(c))
                 outs[i] = out.reshape(c.shape)
             else:

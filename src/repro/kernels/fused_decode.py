@@ -70,7 +70,8 @@ def _side_scratch() -> list:
 
 def _residuals(stage_ref, base, meta_ref, lo_at, opos, oval, bufs, sem, *,
                cols: int, n_valid: int, radius: int):
-    """Write the tile's outliers into the stage, return int32 residuals.
+    """Write the tile's outliers into the stage, then turn the stage into
+    int32 residuals in place.
 
     Staging cells past the ``n_valid`` symbols of the tile (the lane padding
     of the last 128-chunk of a row, the row padding of a tile) hold zero
@@ -84,7 +85,7 @@ def _residuals(stage_ref, base, meta_ref, lo_at, opos, oval, bufs, sem, *,
     r = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     c = ch * C.LANES + jax.lax.broadcasted_iota(jnp.int32, shape, 2)
     valid = (c < cols) & (r * cols + c < n_valid)
-    return jnp.where(valid, stage_ref[...] - radius, 0)
+    stage_ref[...] = jnp.where(valid, stage_ref[...] - radius, 0)
 
 
 def _recon_flat(d, carry_ref, two_eb, out_dtype):
@@ -129,10 +130,11 @@ def _outer_fetch(t, outer, tiles_per_plane: int, levels) -> list:
     return out
 
 
-def _recon_rows(d, t, row_carry, outer, fetched, two_eb, *, tiles_per_plane,
-                out_dtype):
-    """N-D epilogue over one staged tile ``d`` of shape ``(chunks, rows,
-    128)`` (whole rows of the fastest axis, cut into 128-lane chunks).
+def _recon_rows(d_ref, t, row_carry, outer, fetched, two_eb, out_ref, *,
+                tiles_per_plane, rows, cols, out_dtype):
+    """N-D epilogue over one staged tile of residuals ``d_ref`` of shape
+    ``(chunks, rows_pad, 128)`` (whole rows of the fastest axis, cut into
+    128-lane chunks), written to the ``(1, rows, cols)`` block ``out_ref``.
 
     The inverse Lorenzo is the per-axis cumsum chain: inside the tile
     along the row (lanes, then across chunks) and down the rows; across
@@ -147,7 +149,8 @@ def _recon_rows(d, t, row_carry, outer, fetched, two_eb, *, tiles_per_plane,
         ``buf``s; each level adds its block and keeps the sum, and the
         updated blocks go back by DMA.
 
-    Returns one ``(rows, 128)`` output chunk per 128 columns.
+    The chunks run in a loop, so the kernel's size does not grow with the
+    row width; a last chunk narrower than 128 columns follows it.
     """
     if outer:
         @pl.when(t % tiles_per_plane == 0)
@@ -159,33 +162,35 @@ def _recon_rows(d, t, row_carry, outer, fetched, two_eb, *, tiles_per_plane,
             def _():
                 cp.wait()
 
-    out = []
-    run = None
-    for k in range(d.shape[0]):
-        e = C.lane_scan(d[k])
-        if run is not None:                     # carry across 128-chunks
-            e = e + run
-        run = jnp.broadcast_to(e[:, -1:], e.shape)
+    def chunk(k, run):
+        e = C.lane_scan(d_ref[k]) + run         # carry across 128-chunks
         f = C.sublane_scan(e) + row_carry[k]
         row_carry[k] = f[-1:, :]
         for _, buf, _ in outer:
             f = f + buf[k]
             buf[k] = f
-        out.append((f.astype(jnp.float32) * two_eb).astype(out_dtype))
+        x = (f.astype(jnp.float32) * two_eb).astype(out_dtype)
+        return jnp.broadcast_to(e[:, -1:], e.shape), x[:rows]
+
+    def body(k, run):
+        run, x = chunk(k, run)
+        col = pl.multiple_of(k * C.LANES, C.LANES)
+        out_ref[0, :, pl.ds(col, C.LANES)] = x
+        return run
+
+    full, tail = divmod(cols, C.LANES)
+    run = jnp.zeros(d_ref.shape[1:], jnp.int32)
+    if full:
+        run = jax.lax.fori_loop(0, full, body, run)
+    if tail:
+        _, x = chunk(full, run)
+        out_ref[0, :, full * C.LANES:] = x[:, :tail]
     stores = [pltpu.make_async_copy(buf, hbm.at[block], sem)
               for (hbm, buf, sem), (_, _, block) in zip(outer, fetched)]
     for st in stores:
         st.start()
     for st in stores:
         st.wait()
-    return out
-
-
-def _write_rows(out_ref, x, rows: int, cols: int):
-    """Store the per-chunk results into the ``(1, rows, cols)`` block."""
-    for k, xk in enumerate(x):
-        w = min(C.LANES, cols - k * C.LANES)
-        out_ref[0, :, k * C.LANES:k * C.LANES + w] = xk[:rows, :w]
 
 
 def _init_carry(t, carry):
@@ -228,15 +233,15 @@ def _fused_tiles_kernel(rows_ref, start_ref, end_ref, lutb_ref, meta_ref,
                            lut_size=lut_size, cols=cols, tile=tile,
                            ss_max=ss_max)
     n_lanes = start_ref.shape[1] * C.LANES
-    d = _residuals(stage_ref, t * tile, meta_ref, 2 * n_lanes, opos, oval,
-                   (pos_buf, val_buf), sem, cols=cols, n_valid=tile,
-                   radius=radius)
+    _residuals(stage_ref, t * tile, meta_ref, 2 * n_lanes, opos, oval,
+               (pos_buf, val_buf), sem, cols=cols, n_valid=tile,
+               radius=radius)
     if rows is None:                                    # 1-D
-        out_ref[0] = _recon_flat(d[0], carry, teb_ref[0], out_dtype)
+        out_ref[0] = _recon_flat(stage_ref[0], carry, teb_ref[0], out_dtype)
     else:
-        x = _recon_rows(d, t, carry, outer, fetched, teb_ref[0],
-                        tiles_per_plane=tiles_per_plane, out_dtype=out_dtype)
-        _write_rows(out_ref, x, rows, cols)
+        _recon_rows(stage_ref, t, carry, outer, fetched, teb_ref[0], out_ref,
+                    tiles_per_plane=tiles_per_plane, rows=rows, cols=cols,
+                    out_dtype=out_dtype)
 
 
 def _nd_geometry(shape: tuple, rows_per_tile: int):
@@ -384,15 +389,15 @@ def _epilogue_kernel(codes_ref, meta_ref, teb_ref, opos, oval, *refs, cols,
             stage_ref[k, :rows, :w] = codes_ref[
                 0, :, k * C.LANES:k * C.LANES + w].astype(jnp.int32)
         tile = rows * cols
-    d = _residuals(stage_ref, t * tile, meta_ref, 0, opos, oval,
-                   (pos_buf, val_buf), sem, cols=cols, n_valid=tile,
-                   radius=radius)
+    _residuals(stage_ref, t * tile, meta_ref, 0, opos, oval,
+               (pos_buf, val_buf), sem, cols=cols, n_valid=tile,
+               radius=radius)
     if rows is None:
-        out_ref[0] = _recon_flat(d[0], carry, teb_ref[0], out_dtype)
+        out_ref[0] = _recon_flat(stage_ref[0], carry, teb_ref[0], out_dtype)
     else:
-        x = _recon_rows(d, t, carry, outer, fetched, teb_ref[0],
-                        tiles_per_plane=tiles_per_plane, out_dtype=out_dtype)
-        _write_rows(out_ref, x, rows, cols)
+        _recon_rows(stage_ref, t, carry, outer, fetched, teb_ref[0], out_ref,
+                    tiles_per_plane=tiles_per_plane, rows=rows, cols=cols,
+                    out_dtype=out_dtype)
 
 
 def _epilogue_call(codes, ranges, side, two_eb, *, name, kernel,

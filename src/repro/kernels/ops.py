@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from repro.core.huffman import decode as hd
 from repro.core.huffman import encode as he
 from repro.core.huffman.bits import SUBSEQ_BITS
+from repro.core.huffman.pipeline import fused_squeeze, fused_tile_rows
 from repro.kernels import common as C
 from repro.kernels import fused_decode as _fus
 from repro.kernels import histogram as _hist
@@ -141,36 +142,6 @@ def _two_eb_f32(eb):
     return jnp.asarray(eb, jnp.float32).reshape(1) * 2
 
 
-def fused_squeeze(shape):
-    """Canonical fused-path view of ``shape``: unit axes dropped.
-
-    Cumsum along a unit axis is the identity, so reconstruction over the
-    squeezed shape is bitwise the reconstruction over the full shape.  Both
-    the eligibility check (``compressor.fused_unsupported_reason``) and the
-    kernel dispatch below must agree on this rule.
-    """
-    if shape is None:
-        return None
-    sq = tuple(int(s) for s in shape if s != 1)
-    return sq if len(sq) > 1 else None
-
-
-def fused_tile_rows(shape, tile_syms: int) -> int:
-    """Rows per tile for the N-D fused kernels.
-
-    ~``tile_syms`` symbols per tile, rounded to whole rows; beyond 2-D the
-    row count must divide the plane height so no tile crosses a plane
-    boundary (the row-carry reset happens between tiles).
-    """
-    plane_rows, cols = shape[-2], shape[-1]
-    w = max(1, tile_syms // cols)
-    w = min(w, plane_rows)
-    if len(shape) >= 3:
-        while plane_rows % w:
-            w -= 1
-    return w
-
-
 @partial(jax.jit, static_argnames=("max_len", "n_out", "tile_syms", "ss_max",
                                    "radius", "shape", "out_dtype"))
 def decode_write_tiles_fused(units, dec_sym, dec_len, start_bits, end_bits,
@@ -185,7 +156,9 @@ def decode_write_tiles_fused(units, dec_sym, dec_len, start_bits, end_bits,
     list ``opos``/``oval`` written in) without materializing the quant-code
     array.  ``shape`` selects the N-D epilogue (row carry in VMEM, the
     carries of outer axes in HBM); unit axes are squeezed first, so e.g.
-    ``(1, n)`` still takes the 1-D chained-carry kernel.  Returns
+    ``(1, n)`` still takes the 1-D chained-carry kernel.  An N-D
+    ``tile_syms`` is whole rows of the fastest axis that, beyond 2-D,
+    divide the plane height (``pipeline.tile_geometry``).  Returns
     reconstructed ``out_dtype[n_out]`` (flat, C-order).
     """
     sq = fused_squeeze(shape)
@@ -199,18 +172,13 @@ def decode_write_tiles_fused(units, dec_sym, dec_len, start_bits, end_bits,
             ti, lut, side, _two_eb_f32(eb), max_len, int(dec_sym.shape[0]),
             tile_syms, ss_max, radius, out_dtype=out_dtype)
         return out.reshape(ti.n_tiles, -1)[:, :tile_syms].reshape(-1)[:n_out]
-    # N-D: re-tile along whole rows of the fastest axis.  The tile size
-    # changes, so the lane budget must be re-derived for the new tile.
-    from repro.core.huffman.pipeline import ss_max_for_tile
-
-    rows_per_tile = fused_tile_rows(sq, tile_syms)
-    block = rows_per_tile * sq[-1]
-    ss_max_nd = ss_max_for_tile(block, max_len)
+    rows_per_tile, rem = divmod(tile_syms, sq[-1])
+    assert rem == 0 and rows_per_tile, (sq, tile_syms)
     ti = tile_inputs(units, start_bits, end_bits, offsets, total_bits, n_out,
-                     block, ss_max_nd, lut_base, outlier_pos=opos)
+                     tile_syms, ss_max, lut_base, outlier_pos=opos)
     out = _fus.decode_tiles_fused_nd(
         ti, lut, side, _two_eb_f32(eb), max_len, int(dec_sym.shape[0]),
-        rows_per_tile, sq, ss_max_nd, radius, out_dtype=out_dtype)
+        rows_per_tile, sq, ss_max, radius, out_dtype=out_dtype)
     return out.reshape(-1)[:n_out]
 
 

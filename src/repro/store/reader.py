@@ -330,7 +330,8 @@ class Archive:
                         decoded = sz.decompress_batch(
                             ok_cs, method=method, backend=be,
                             strategy=cfg.strategy, t_high=t_high,
-                            plans=ok_plans, fused=fused)
+                            plans=ok_plans, fused=fused,
+                            tile_syms=cfg.tile_syms)
                         outs = dict(zip(ok_names, decoded))
                     except hp.DecodeGuardError:
                         # Salvage the group chunk-by-chunk so one malformed
@@ -340,7 +341,8 @@ class Archive:
                                 outs[n] = sz.decompress(
                                     c, method=method, backend=be,
                                     strategy=cfg.strategy, t_high=t_high,
-                                    plan=p, fused=fused)
+                                    tile_syms=cfg.tile_syms, plan=p,
+                                    fused=fused)
                             except hp.DecodeGuardError as e:
                                 failed[n] = e
 

@@ -152,6 +152,31 @@ def test_decode_tiles_fused_nd(compiled, shape, rows_per_tile):
              S((1,), jnp.float32))
 
 
+def test_decode_tiles_fused_nd_derived(compiled):
+    """The nyx field at the geometry ``pipeline.tile_geometry`` derives
+    for it: 128 rows of 512 symbols a tile, one register of 1024 lanes."""
+    S, compile_ = compiled
+    compile_(functools.partial(F.decode_tiles_fused_nd, max_len=MAX_LEN,
+                               lut_size=1 << MAX_LEN, rows_per_tile=128,
+                               shape=(512, 512, 512), ss_max=1024,
+                               radius=RADIUS),
+             _tile_inputs(S, 16, 1024, 2),
+             S((LUT_ROWS, C.LANES), jnp.int32), _side(S),
+             S((1,), jnp.float32))
+
+
+def test_decode_tiles_fused_1d_derived(compiled):
+    """The largest flat tile the derivation asks for at 128 symbols a
+    window: 131072 symbols over 1024 lanes."""
+    S, compile_ = compiled
+    compile_(functools.partial(F.decode_tiles_fused, max_len=MAX_LEN,
+                               lut_size=1 << MAX_LEN, tile_syms=128 * 1024,
+                               ss_max=1024, radius=RADIUS),
+             _tile_inputs(S, 16, 1024, 2),
+             S((LUT_ROWS, C.LANES), jnp.int32), _side(S),
+             S((1,), jnp.float32))
+
+
 def test_lorenzo_quantize1d(compiled):
     S, compile_ = compiled
     rows = 4 * L.MAX_BLOCK_ROWS
