@@ -15,11 +15,12 @@ from repro.configs.deepseek_v3_671b import CONFIG as _dsv3
 from repro.configs.rwkv6_3b import CONFIG as _rwkv6
 from repro.configs.qwen2_vl_72b import CONFIG as _qwen2vl
 from repro.configs.whisper_base import CONFIG as _whisper
+from repro.configs.kimi_linear_48b_a3b import CONFIG as _kimi_linear
 
 REGISTRY = {
     c.name: c
     for c in [_qwen3, _sc2, _danube, _qwen25, _zamba2, _qwen2moe, _dsv3,
-              _rwkv6, _qwen2vl, _whisper]
+              _rwkv6, _qwen2vl, _whisper, _kimi_linear]
 }
 
 ARCH_IDS = list(REGISTRY)

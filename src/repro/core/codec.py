@@ -239,13 +239,27 @@ class Codec:
 
     # -- single tensors ------------------------------------------------------
 
-    def compress(self, x) -> Compressed:
+    def compress(self, x, *, value_range: "float | None" = None
+                 ) -> Compressed:
+        """Compress one tensor.  ``value_range``: the range of the whole
+        tensor ``x`` is a part of, for a relative bound shared by all its
+        parts (``compressor.compress``)."""
         c = self.config
         with trace.span("codec.compress"):
             return compressor.compress(x, eb=c.eb, mode=c.mode,
                                        radius=c.radius, max_len=c.max_len,
                                        subseqs_per_seq=c.subseqs_per_seq,
-                                       encode_backend=self.encode_backend)
+                                       encode_backend=self.encode_backend,
+                                       value_range=value_range)
+
+    def value_range(self, x) -> "float | None":
+        """The range ``max(x) - min(x)`` this codec's relative bound takes
+        over ``x``, for ``compress(part, value_range=)`` of each part of
+        ``x``; None under an absolute bound, which reads no range."""
+        if self.config.mode != "rel":
+            return None
+        with trace.span("codec.value_range"):
+            return compressor.value_range(x)
 
     def build_plan(self, stream, codebook) -> hp.DecoderPlan:
         """Phase 1-3 plan under this codec's (method, backend, t_high)."""

@@ -98,6 +98,12 @@ def _value_stats(x):
     return jnp.max(x) - jnp.min(x), jnp.max(jnp.abs(x))
 
 
+def value_range(x) -> float:
+    """``max(x) - min(x)``, the range a relative bound is taken over, read
+    as :func:`compress` reads it."""
+    return float(trace.to_host(_value_stats(trace.to_device(x))[0]))
+
+
 @jax.jit
 def _outlier_prefix(outlier):
     """Inclusive prefix count of the flattened outlier mask, and its total."""
@@ -147,11 +153,15 @@ def compress(
     max_len: int = cb.DEFAULT_MAX_LEN,
     subseqs_per_seq: int = he.DEFAULT_SUBSEQS_PER_SEQ,
     encode_backend: str = "ref",
+    value_range: "float | None" = None,
 ) -> Compressed:
     """Compress a float tensor with error bound ``eb``.
 
     mode="rel": bound is ``eb * (max(x) - min(x))`` (the paper's setting,
     "relative error bound 1e-3"); mode="abs": bound is ``eb`` directly.
+    ``value_range`` replaces ``max(x) - min(x)`` under "rel": a part of a
+    larger tensor passes the whole tensor's range (:func:`value_range`), so
+    that every part is quantized on the one lattice of the whole.
 
     ``encode_backend`` selects the write-path pipeline
     (``pipeline.available_encode_backends()``): "ref" is the host path
@@ -168,7 +178,8 @@ def compress(
     with trace.span("compress.stats"):
         span, max_abs = (float(trace.to_host(v)) for v in _value_stats(x))
     if mode == "rel":
-        rng = span if span > 0 else 1.0
+        rng = span if value_range is None else value_range
+        rng = rng if rng > 0 else 1.0
         abs_eb = eb * rng
     elif mode == "abs":
         rng = 1.0

@@ -151,13 +151,14 @@ def main(argv=None):
                                           page_in_blocks_batched)
         from repro.store import PageLostError
 
-        # Only tensors with a kv_len sequence axis at axis 2 are pageable
-        # (ssm/rwkv recurrent states have no token axis to evict).
-        keys = [k for k in cache
-                if k in ("k", "v", "latent", "k_scale", "v_scale")]
+        # The cache layout decides what is paged by token ranges: tensors
+        # without a token axis (recurrent states, cross-attention k/v) are
+        # never sliced.
         offload_dir = args.kv_offload_dir or tempfile.mkdtemp(
             prefix="kv_blocks_")
-        pager = KVPager(offload_dir, codec=kv_codec)
+        pager = KVPager(offload_dir, codec=kv_codec,
+                        layout=D.cache_layout(cfg))
+        keys = pager.pageable(cache, args.prompt_len)
         snapshot = {k: np.asarray(cache[k], np.float32) for k in keys}
         t0 = time.time()
         cache, block_ids = offload_prefix(cache, pager, args.prompt_len,
@@ -211,12 +212,7 @@ def main(argv=None):
             if errors:
                 raise errors[0]
             for bid, tensors in results.items():
-                meta = pager.block_meta(bid)
-                span = ((slice(None),) * pager.seq_axis
-                        + (slice(meta["lo"], meta["hi"]),))
-                for name, block in tensors.items():
-                    cache[name] = cache[name].at[span].set(
-                        jnp.asarray(block, cache[name].dtype))
+                cache = pager.install(cache, bid, tensors)
         else:
             cache = page_in_blocks_batched(cache, pager, block_ids,
                                            on_lost=on_lost)

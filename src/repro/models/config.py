@@ -11,7 +11,7 @@ import jax.numpy as jnp
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                 # dense | moe | mla_moe | hybrid_ssm | rwkv | encdec | vlm
+    family: str                 # dense | moe | mla_moe | kda_mla | hybrid_ssm | rwkv | encdec | vlm
     n_layers: int
     d_model: int
     n_heads: int
@@ -39,6 +39,8 @@ class ModelConfig:
     first_k_dense: int = 0                # deepseek-v3: first layers dense
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.001
+    router_act: str = "softmax"           # "softmax" | "sigmoid" (kimi-linear)
+    routed_scale: float = 1.0             # routed experts' output scale
 
     # --- MLA (deepseek-v3) ---
     mla: bool = False
@@ -48,6 +50,14 @@ class ModelConfig:
     qk_nope_dim: int = 128
     v_head_dim: int = 128
     mtp: bool = False                     # multi-token-prediction extra head
+    mla_nope: bool = False                # MLA without rotary embeddings
+
+    # --- KDA linear attention beside MLA (kimi-linear) ---
+    kda_heads: int = 0
+    kda_head_dim: int = 0                 # dk = dv of the recurrent state
+    kda_conv: int = 0                     # short-convolution taps on q, k, v
+    kda_layers: tuple = ()                # 1-indexed layers that are KDA
+    mla_layers: tuple = ()                # 1-indexed layers that are MLA
 
     # --- SSM / hybrid (zamba2, rwkv6) ---
     ssm_state: int = 0                    # mamba2 state dim N
@@ -113,8 +123,11 @@ class ModelConfig:
                          first_k_dense=min(self.first_k_dense, 1),
                          capacity_factor=4.0)
         if self.mla:
-            small.update(q_lora_rank=64, kv_lora_rank=32, qk_rope_dim=16,
+            small.update(q_lora_rank=64 if self.q_lora_rank else 0,
+                         kv_lora_rank=32, qk_rope_dim=16,
                          qk_nope_dim=16, v_head_dim=32, d_head=32)
+        if self.kda_layers:
+            small.update(kda_heads=2, kda_head_dim=16)
         if self.ssm_state:
             small.update(ssm_state=16, ssm_heads=4, ssm_chunk=32)
         if self.hybrid_attn_every:
@@ -127,6 +140,12 @@ class ModelConfig:
         if self.swa_window:
             small.update(swa_window=32)
         small.update(overrides)
+        if self.kda_layers and "mla_layers" not in overrides:
+            # the published pattern over the layers kept, ending in MLA
+            n = small["n_layers"]
+            mla = tuple(i for i in self.mla_layers if i < n) + (n,)
+            small.update(mla_layers=mla, kda_layers=tuple(
+                i for i in range(1, n + 1) if i not in mla))
         return dataclasses.replace(self, **small)
 
 
@@ -137,8 +156,9 @@ def count_params(cfg: ModelConfig) -> int:
 
     def attn_params():
         if cfg.mla:
-            q = d * cfg.q_lora_rank + cfg.q_lora_rank * nq * (
-                cfg.qk_nope_dim + cfg.qk_rope_dim)
+            q = (d * cfg.q_lora_rank + cfg.q_lora_rank * nq * (
+                cfg.qk_nope_dim + cfg.qk_rope_dim)) if cfg.q_lora_rank else \
+                d * nq * (cfg.qk_nope_dim + cfg.qk_rope_dim)
             kv = d * (cfg.kv_lora_rank + cfg.qk_rope_dim) + cfg.kv_lora_rank \
                 * nq * (cfg.qk_nope_dim + cfg.v_head_dim)
             o = nq * cfg.v_head_dim * d
@@ -172,6 +192,15 @@ def count_params(cfg: ModelConfig) -> int:
         total += dense_l * mlp_params(cfg.d_ff if not cfg.moe else
                                       cfg.d_expert * (cfg.top_k + cfg.n_shared_experts))
         total += moe_l * moe_params()
+    elif cfg.family == "kda_mla":
+        h, dk = cfg.kda_heads, cfg.kda_head_dim
+        kda = 4 * d * h * dk + cfg.kda_conv * 3 * h * dk + 2 * dk * h * dk \
+            + 2 * d * dk + d * h
+        dense_l = cfg.first_k_dense
+        total += len(cfg.kda_layers) * kda
+        total += len(cfg.mla_layers) * attn_params()
+        total += dense_l * mlp_params(cfg.d_ff)
+        total += (cfg.n_layers - dense_l) * moe_params()
     elif cfg.family == "hybrid_ssm":
         # Mamba2 layers carry no separate MLP; d_ff belongs to the single
         # weight-shared attention block (Zamba2 design).

@@ -3,6 +3,9 @@
 Cache layouts (stacked over layers, scan-carried):
   dense/vlm/moe : k,v   (L, B, S_kv, Hkv, Dh)   -- S_kv = window for SWA
   mla_moe       : latent (L, B, S_kv, kvr + dr) -- compressed latent cache
+  kda_mla       : latent (n_mla, B, S, kvr + dr) + kda_state
+                  (n_kda, B, H, dk, dk) fp32 + kda_conv (n_kda, B, 3 H dk,
+                  K - 1) fp32
   hybrid_ssm    : ssm (L, B, H, N, P) fp32  + attn k,v (n_attn, B, S, Hkv, Dh)
   rwkv          : state (L, B, H, dk, dv) fp32 + shift carries (L, B, d) x2
   encdec        : self k,v (L, B, S, Hkv, Dh) + cross k,v precomputed
@@ -15,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import attention as A
+from repro.models import kda as KDA
 from repro.models import layers as L
 from repro.models import mla as MLA
 from repro.models import moe as MOE
@@ -23,54 +27,90 @@ from repro.models import ssm as SSM
 from repro.models.config import ModelConfig
 
 
-def cache_spec(cfg: ModelConfig, batch: int, kv_len: int, dtype=None):
-    """Shape/dtype tree of the decode cache (also used by input_specs())."""
+#: The token axis of every cache tensor that has one (each family's k/v,
+#: latent and int8 scales hold a sequence's tokens on axis 2).
+TOKEN_AXIS = 2
+
+
+def _cache_entries(cfg: ModelConfig, batch: int, kv_len: int, dtype=None):
+    """``{name: (shape, dtype, token_axis)}`` of the decode cache.  A
+    tensor's ``token_axis`` is None where it holds per-sequence state with
+    no token axis (a recurrent state, a convolution window, the encoder's
+    cross-attention k/v): a pager never slices it by token ranges."""
     dt = dtype or cfg.cdt
     fam = cfg.family
+    tok = TOKEN_AXIS
     hkv, dh = cfg.n_kv_heads, cfg.head_dim
     if cfg.swa_window:
         kv_len = min(kv_len, cfg.swa_window)
     if fam in ("dense", "vlm"):
         if cfg.kv_quant:
             return {
-                "k": ((cfg.n_layers, batch, kv_len, hkv, dh), jnp.int8),
-                "v": ((cfg.n_layers, batch, kv_len, hkv, dh), jnp.int8),
-                "k_scale": ((cfg.n_layers, batch, kv_len, hkv), jnp.float32),
-                "v_scale": ((cfg.n_layers, batch, kv_len, hkv), jnp.float32),
+                "k": ((cfg.n_layers, batch, kv_len, hkv, dh), jnp.int8, tok),
+                "v": ((cfg.n_layers, batch, kv_len, hkv, dh), jnp.int8, tok),
+                "k_scale": ((cfg.n_layers, batch, kv_len, hkv), jnp.float32,
+                            tok),
+                "v_scale": ((cfg.n_layers, batch, kv_len, hkv), jnp.float32,
+                            tok),
             }
-        return {"k": ((cfg.n_layers, batch, kv_len, hkv, dh), dt),
-                "v": ((cfg.n_layers, batch, kv_len, hkv, dh), dt)}
+        return {"k": ((cfg.n_layers, batch, kv_len, hkv, dh), dt, tok),
+                "v": ((cfg.n_layers, batch, kv_len, hkv, dh), dt, tok)}
     if fam == "moe":
         n = cfg.n_layers
-        return {"k": ((n, batch, kv_len, hkv, dh), dt),
-                "v": ((n, batch, kv_len, hkv, dh), dt)}
+        return {"k": ((n, batch, kv_len, hkv, dh), dt, tok),
+                "v": ((n, batch, kv_len, hkv, dh), dt, tok)}
     if fam == "mla_moe":
         width = cfg.kv_lora_rank + cfg.qk_rope_dim
-        return {"latent": ((cfg.n_layers, batch, kv_len, width), dt)}
+        return {"latent": ((cfg.n_layers, batch, kv_len, width), dt, tok)}
+    if fam == "kda_mla":
+        width = cfg.kv_lora_rank + cfg.qk_rope_dim
+        h, dk = KDA.kda_dims(cfg)
+        n_kda = len(cfg.kda_layers)
+        return {
+            "latent": ((len(cfg.mla_layers), batch, kv_len, width), dt, tok),
+            "kda_state": ((n_kda, batch, h, dk, dk), jnp.float32, None),
+            "kda_conv": ((n_kda, batch, 3 * h * dk, cfg.kda_conv - 1),
+                         jnp.float32, None),
+        }
     if fam == "hybrid_ssm":
         dv, h, p = SSM.ssm_dims(cfg)
         n_attn = max(1, cfg.n_layers // max(cfg.hybrid_attn_every, 1))
         return {
-            "ssm": ((cfg.n_layers, batch, h, cfg.ssm_state, p), jnp.float32),
-            "k": ((n_attn, batch, kv_len, hkv, dh), dt),
-            "v": ((n_attn, batch, kv_len, hkv, dh), dt),
+            "ssm": ((cfg.n_layers, batch, h, cfg.ssm_state, p), jnp.float32,
+                    None),
+            "k": ((n_attn, batch, kv_len, hkv, dh), dt, tok),
+            "v": ((n_attn, batch, kv_len, hkv, dh), dt, tok),
         }
     if fam == "rwkv":
         h, dh_r = RWKV.rwkv_dims(cfg)
         return {
-            "state": ((cfg.n_layers, batch, h, dh_r, dh_r), jnp.float32),
-            "tshift": ((cfg.n_layers, batch, cfg.d_model), dt),
-            "cshift": ((cfg.n_layers, batch, cfg.d_model), dt),
+            "state": ((cfg.n_layers, batch, h, dh_r, dh_r), jnp.float32,
+                      None),
+            "tshift": ((cfg.n_layers, batch, cfg.d_model), dt, None),
+            "cshift": ((cfg.n_layers, batch, cfg.d_model), dt, None),
         }
     if fam == "encdec":
         n = cfg.n_layers
         return {
-            "k": ((n, batch, kv_len, hkv, dh), dt),
-            "v": ((n, batch, kv_len, hkv, dh), dt),
-            "xk": ((n, batch, cfg.encoder_seq, hkv, dh), dt),
-            "xv": ((n, batch, cfg.encoder_seq, hkv, dh), dt),
+            "k": ((n, batch, kv_len, hkv, dh), dt, tok),
+            "v": ((n, batch, kv_len, hkv, dh), dt, tok),
+            "xk": ((n, batch, cfg.encoder_seq, hkv, dh), dt, None),
+            "xv": ((n, batch, cfg.encoder_seq, hkv, dh), dt, None),
         }
     raise ValueError(fam)
+
+
+def cache_spec(cfg: ModelConfig, batch: int, kv_len: int, dtype=None):
+    """Shape/dtype tree of the decode cache (also used by input_specs())."""
+    return {k: (shape, dt) for k, (shape, dt, _) in
+            _cache_entries(cfg, batch, kv_len, dtype).items()}
+
+
+def cache_layout(cfg: ModelConfig) -> dict:
+    """``{name: token_axis}`` of the decode cache (``None``: no token axis,
+    the tensor is paged out whole, as a snapshot).  ``store.KVPager`` takes
+    it as ``layout=``."""
+    return {k: ax for k, (_, _, ax) in _cache_entries(cfg, 1, 1).items()}
 
 
 def init_cache(cfg: ModelConfig, batch: int, kv_len: int):
@@ -176,6 +216,21 @@ def forward_decode(params, token, cache, pos, cfg: ModelConfig):
         x, lat_moe = jax.lax.scan(mbody, x, (params["layers"], lat_moe))
         new_cache["latent"] = jnp.concatenate([lat_dense, lat_moe], axis=0) \
             if nk else lat_moe
+
+    elif fam == "kda_mla":
+        lat, st, win = cache["latent"], cache["kda_state"], cache["kda_conv"]
+        for i, (kind, j) in enumerate(KDA.layer_order(cfg)):
+            lp = jax.tree.map(lambda a: a[j], params[kind])
+            xn = L.rms_norm(x, lp["ln1"])
+            if kind == "kda":
+                y, s_j, w_j = KDA.kda_block(xn, lp["kda"], cfg, st[j], win[j])
+                st, win = st.at[j].set(s_j), win.at[j].set(
+                    w_j.astype(win.dtype))
+            else:
+                y, l_j = MLA.mla_decode(xn, lp["attn"], cfg, lat[j], pos)
+                lat = lat.at[j].set(l_j)
+            x, _ = KDA.ffn(x + y, params, cfg, i)
+        new_cache.update(latent=lat, kda_state=st, kda_conv=win)
 
     elif fam == "hybrid_ssm":
         every = max(cfg.hybrid_attn_every, 1)

@@ -20,7 +20,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.codec import Codec, default_codec
-from repro.store.paging import KVPager  # noqa: F401  (re-export)
+from repro.runtime import trace
+from repro.store.paging import KVPager, itemsize
 
 
 @dataclasses.dataclass
@@ -35,7 +36,8 @@ class CompressedCache:
 
     @property
     def original_bytes(self) -> int:
-        return sum(int(np.prod(s)) * 2 for s in self.orig_shapes.values())
+        return sum(int(np.prod(s)) * itemsize(self.orig_dtypes[n])
+                   for n, s in self.orig_shapes.items())
 
     @property
     def ratio(self) -> float:
@@ -87,13 +89,16 @@ def offload_prefix(cache: dict, pager: KVPager, n_tokens: int,
     """Evict tokens [0, n_tokens) of the cache in fixed-size blocks.
 
     Each block becomes one store archive (one chunk per cache tensor,
-    codebooks deduped); the evicted region of ``cache`` is zeroed.  Returns
-    ``(cache, block_ids)`` in eviction order.
+    codebooks deduped); the evicted region of ``cache`` is zeroed.  Each
+    tensor's relative bound is taken once, over the whole tensor, before
+    the first block.  Returns ``(cache, block_ids)`` in eviction order.
     """
+    ranges = pager.value_ranges(cache, pager.pageable(cache, 1, keys))
     ids = []
     for lo in range(0, n_tokens, block_tokens):
         cache, bid = pager.offload(cache, lo, min(lo + block_tokens,
-                                                  n_tokens), keys=keys)
+                                                  n_tokens), keys=keys,
+                                   value_ranges=ranges)
         ids.append(bid)
     return cache, ids
 
@@ -134,10 +139,77 @@ def page_in_blocks_batched(cache: dict, pager: KVPager, block_ids,
     """
     decoded = pager.fetch_many(block_ids, on_lost=on_lost)
     for bid, tensors in decoded.items():
-        meta = pager.block_meta(bid)
-        span = ((slice(None),) * pager.seq_axis
-                + (slice(meta["lo"], meta["hi"]),))
-        for name, block in tensors.items():
-            cache[name] = cache[name].at[span].set(
-                jnp.asarray(block, cache[name].dtype))
+        cache = pager.install(cache, bid, tensors)
     return cache
+
+
+# ---------------------------------------------------------------------------
+# Preemption and resume of whole sequences
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass
+class Preempted:
+    """What a preemption left in the store, enough to resume it.
+
+    ``pages``: block ids of the token pages, in token order;
+    ``snapshot``: block id of the state snapshot (None for a cache without
+    state tensors); ``specs``: ``{name: (shape, dtype)}`` of the cache;
+    ``blocks``: ``{block_id: meta}`` so that another pager over the same
+    directory (a fresh ``Codec``, a restarted process) can adopt them.
+    """
+
+    pages: list
+    snapshot: "int | None"
+    specs: dict
+    blocks: dict
+
+    @property
+    def block_ids(self) -> list:
+        return self.pages + ([] if self.snapshot is None
+                             else [self.snapshot])
+
+
+def preempt(cache: dict, pager: KVPager, *,
+            page_tokens: int = 1024) -> Preempted:
+    """Move a preempted sequence batch's cache into the store.
+
+    The whole token axis of every tensor with one goes out in
+    ``page_tokens`` pages (``KVPager.offload``), each tensor's relative
+    bound taken once over the whole tensor; the tensors without a token
+    axis go out whole in one snapshot archive (``KVPager.snapshot``).
+    ``cache`` is not changed.  Returns the handle :func:`resume` takes.
+    """
+    cache = dict(cache)
+    specs = {k: (tuple(a.shape), str(a.dtype)) for k, a in cache.items()}
+    with trace.operation("pager.preempt"):
+        paged = pager.pageable(cache, 1)
+        pages = []
+        if paged:
+            n_tokens = cache[paged[0]].shape[pager.token_axis(paged[0])]
+            cache, pages = offload_prefix(cache, pager, n_tokens,
+                                          block_tokens=page_tokens,
+                                          keys=paged)
+        state = pager.state_keys(cache)
+        snap = pager.snapshot(cache, state) if state else None
+    ids = pages + ([] if snap is None else [snap])
+    return Preempted(pages=pages, snapshot=snap, specs=specs,
+                     blocks={b: pager.block_meta(b) for b in ids})
+
+
+def resume(pager: KVPager, handle: Preempted) -> dict:
+    """Bring a preempted cache back: stage and decode every page and the
+    snapshot in one class-merged ``KVPager.fetch_many``, then install them
+    into a zeroed cache of the handle's shapes.
+
+    Blocks the pager does not hold (another pager wrote them) are adopted
+    from the handle first.  A lost block raises ``PageLostError``.
+    """
+    with trace.operation("pager.resume"):
+        held = set(pager.resident_blocks)
+        for bid in handle.block_ids:
+            if bid not in held:
+                pager.adopt_block(bid, handle.blocks[bid])
+        cache = {k: jnp.zeros(shape, jnp.dtype(dt))
+                 for k, (shape, dt) in handle.specs.items()}
+        return page_in_blocks_batched(cache, pager, handle.block_ids)

@@ -23,10 +23,13 @@ def init_mla(key, cfg: ModelConfig):
     qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     ks = L.split_keys(key, 8)
+    # q_lora_rank 0: queries projected directly (kimi-linear)
+    q = ({"wdq": L.dense_init(ks[0], (d, qr), cfg.pdt),
+          "q_norm": jnp.ones((qr,), cfg.pdt),
+          "wuq": L.dense_init(ks[1], (qr, h, dn + dr), cfg.pdt)} if qr else
+         {"wq": L.dense_init(ks[0], (d, h, dn + dr), cfg.pdt)})
     return {
-        "wdq": L.dense_init(ks[0], (d, qr), cfg.pdt),
-        "q_norm": jnp.ones((qr,), cfg.pdt),
-        "wuq": L.dense_init(ks[1], (qr, h, dn + dr), cfg.pdt),
+        **q,
         "wdkv": L.dense_init(ks[2], (d, kvr + dr), cfg.pdt),
         "kv_norm": jnp.ones((kvr,), cfg.pdt),
         "wuk": L.dense_init(ks[3], (kvr, h, dn), cfg.pdt),
@@ -36,18 +39,22 @@ def init_mla(key, cfg: ModelConfig):
 
 
 def _latents(x, p, cfg: ModelConfig, positions):
-    """Project to q (B,S,H,dn+dr), c_kv (B,S,kvr), k_rope (B,S,1,dr)."""
-    dn, dr = cfg.qk_nope_dim, cfg.qk_rope_dim
-    cq = L.rms_norm(x @ p["wdq"].astype(x.dtype), p["q_norm"])
-    q = jnp.einsum("bsr,rhe->bshe", cq, p["wuq"].astype(x.dtype))
-    q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = L.apply_rope(q_rope, positions, cfg.rope_theta)
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    """Project to q (B,S,H,dn+dr), c_kv (B,S,kvr), k_rope (B,S,1,dr).
 
+    Under ``cfg.mla_nope`` the dr columns are kept but not rotated."""
+    dn = cfg.qk_nope_dim
+    if "wq" in p:
+        q = jnp.einsum("bsd,dhe->bshe", x, p["wq"].astype(x.dtype))
+    else:
+        cq = L.rms_norm(x @ p["wdq"].astype(x.dtype), p["q_norm"])
+        q = jnp.einsum("bsr,rhe->bshe", cq, p["wuq"].astype(x.dtype))
     ckv_full = x @ p["wdkv"].astype(x.dtype)
     c_kv = L.rms_norm(ckv_full[..., : cfg.kv_lora_rank], p["kv_norm"])
     k_rope = ckv_full[..., cfg.kv_lora_rank:][..., None, :]  # (B,S,1,dr)
-    k_rope = L.apply_rope(k_rope, positions, cfg.rope_theta)
+    if not cfg.mla_nope:
+        q_rope = L.apply_rope(q[..., dn:], positions, cfg.rope_theta)
+        q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+        k_rope = L.apply_rope(k_rope, positions, cfg.rope_theta)
     return q, c_kv, k_rope
 
 

@@ -90,9 +90,11 @@ def _moe_tokens(xt, p, cfg: ModelConfig):
 
     logits = xt.astype(jnp.float32) @ p["router"]
     logits = pspec.shard(logits, pspec.BATCH, None)
-    gates = jax.nn.softmax(logits, axis=-1)                  # (T, E)
+    gates = (jax.nn.sigmoid(logits) if cfg.router_act == "sigmoid"
+             else jax.nn.softmax(logits, axis=-1))           # (T, E)
     topv, topi = jax.lax.top_k(gates, k)                     # (T, k)
-    topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+    topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9) \
+        * cfg.routed_scale
 
     # Capacity + ranks.  assign: (T, E) in {0,1}; rank = exclusive cumsum.
     cap = int(max(1, round(t * k / e * cfg.capacity_factor)))

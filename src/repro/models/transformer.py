@@ -8,6 +8,8 @@ Families:
   dense / vlm  -- GQA attention (+SWA/qk-norm/bias/M-RoPE) + gated MLP
   moe          -- GQA attention + shared/routed top-k MoE
   mla_moe      -- MLA attention + MoE (+ optional MTP head), DeepSeek-V3
+  kda_mla      -- KDA linear-attention and MLA layers + MoE, Kimi Linear
+                  (layers unrolled in the config's order, ``models/kda.py``)
   hybrid_ssm   -- Mamba2 blocks + weight-shared attention block every k
   rwkv         -- RWKV6 time-mix + channel-mix
   encdec       -- Whisper: bidirectional encoder over stubbed frames +
@@ -22,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import attention as A
+from repro.models import kda as KDA
 from repro.models import layers as L
 from repro.models import mla as MLA
 from repro.models import moe as MOE
@@ -144,6 +147,21 @@ def init_model(key, cfg: ModelConfig):
                 "block": _init_dense_layer(kk[1], cfg),
                 "norm": jnp.ones((d,), cfg.pdt),
             }
+    elif fam == "kda_mla":
+        ones = jnp.ones((d,), cfg.pdt)
+        params["kda"] = _stack_init(
+            lambda k: {"ln1": ones, "kda": KDA.init_kda(k, cfg)}, ks[2],
+            len(cfg.kda_layers))
+        params["mla"] = _stack_init(
+            lambda k: {"ln1": ones, "attn": MLA.init_mla(k, cfg)}, ks[3],
+            len(cfg.mla_layers))
+        if cfg.first_k_dense:
+            params["dense_layers"] = _stack_init(
+                lambda k: {"ln2": ones, "mlp": _init_mlp(k, cfg)}, ks[4],
+                cfg.first_k_dense)
+        params["layers"] = _stack_init(
+            lambda k: {"ln2": ones, "moe": MOE.init_moe(k, cfg)}, ks[5],
+            cfg.n_layers - cfg.first_k_dense)
     elif fam == "hybrid_ssm":
         params["layers"] = _stack_init(_init_ssm_layer, ks[2],
                                        cfg.n_layers, cfg)
@@ -270,6 +288,20 @@ def forward_hidden(params, tokens, cfg: ModelConfig, extra_embeds=None):
             return x, aux
         x, auxs = jax.lax.scan(_maybe_remat(mbody, cfg), x, params["layers"])
         aux_total = aux_total + jnp.sum(auxs) * cfg.router_aux_coef
+
+    elif fam == "kda_mla":
+        h, dk = KDA.kda_dims(cfg)
+        state0 = jnp.zeros((bsz, h, dk, dk), jnp.float32)
+        window0 = jnp.zeros((bsz, 3 * h * dk, cfg.kda_conv - 1), x.dtype)
+        for i, (kind, j) in enumerate(KDA.layer_order(cfg)):
+            lp = jax.tree.map(lambda a: a[j], params[kind])
+            xn = L.rms_norm(x, lp["ln1"])
+            if kind == "kda":
+                y, _, _ = KDA.kda_block(xn, lp["kda"], cfg, state0, window0)
+            else:
+                y = MLA.mla_block(xn, lp["attn"], cfg, positions)
+            x, aux = KDA.ffn(x + y, params, cfg, i)
+            aux_total = aux_total + aux * cfg.router_aux_coef
 
     elif fam == "hybrid_ssm":
         every = max(cfg.hybrid_attn_every, 1)

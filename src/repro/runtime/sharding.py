@@ -105,6 +105,12 @@ def param_spec(path: str, shape: tuple, mesh) -> P:
     if path.endswith(("mlp/wd", "mlp/wo", "cmix/wv")):
         return spec(tp, fsdp)                         # (ff, d)
 
+    # --- KDA (kimi-linear) ---
+    if path.endswith(("kda/wq", "kda/wk", "kda/wv", "kda/wfb", "kda/wgb")):
+        return spec(fsdp, tp)                         # (d or dk, H dk)
+    if path.endswith("kda/wo"):
+        return spec(tp, fsdp)                         # (H dk, d)
+
     # --- SSM / RWKV ---
     if path.endswith("ssm/win"):
         return spec(fsdp, tp)

@@ -1,12 +1,27 @@
 """KV-cache block paging through the compressed tensor store.
 
-The serving cache (``models/decode``: k/v of shape (L, B, S, H, D)) is
-large, cold outside the active attention window, and tolerant of bounded
-error -- the paper's in-memory-compression profile.  ``KVPager`` evicts a
-token range of every pageable cache tensor into one ``.szt`` archive
-(one chunk per tensor, codebooks deduped across K/V) and pages it back on
-demand with the batched decoder.  Repeated page-ins of the same block hit
-the plan cache, so the steady-state page-in cost is pure phase-4 decode.
+The serving cache (``models/decode``: k/v of shape (L, B, S, H, D), a
+latent (L, B, S, W), recurrent states) is large, cold outside the active
+attention window, and tolerant of bounded error -- the paper's
+in-memory-compression profile.  ``KVPager`` evicts a token range of every
+pageable cache tensor into one ``.szt`` archive (one chunk per tensor,
+codebooks deduped across K/V) and pages it back on demand with the batched
+decoder.  Repeated page-ins of the same block hit the plan cache, so the
+steady-state page-in cost is pure phase-4 decode.
+
+The cache's layout (``models/decode.cache_layout``: ``{name: token_axis}``)
+says which axis of each tensor holds tokens.  Only tensors with a token
+axis are paged by token ranges; a tensor without one (a recurrent state, a
+convolution window) is never sliced: ``snapshot`` compresses it whole into
+an archive of its own, and a page-in of that block restores it whole.  A
+cache that declares no layout pages every tensor on ``seq_axis``.
+
+Under a relative bound, ``offload`` bounds each page by its own value
+range unless it is given ``value_ranges``.  A caller that pages a whole
+tensor out (``models/kvcache.offload_prefix``, ``preempt``) reads them once
+(:meth:`KVPager.value_ranges`), so every page of a tensor is compressed
+against the range of the whole tensor and the pages share one quantization
+lattice: the bound is the cache's, not a page's.
 
 The paged region is zeroed after eviction: attention over masked-out
 positions never reads it, and the zeros compress to nothing if the block
@@ -32,6 +47,7 @@ import numpy as np
 from repro.core.cache import PlanCache
 from repro.core.codec import Codec, default_codec
 from repro.core.huffman import pipeline as hp
+from repro.runtime import trace
 from repro.store import format as F
 from repro.store.reader import Archive
 from repro.store.writer import ArchiveWriter
@@ -52,11 +68,19 @@ class PageLostError(F.StoreError):
         self.block_id = block_id
 
 
-def _pageable(name: str, arr, seq_axis: int, hi: int) -> bool:
-    dt = np.dtype(str(arr.dtype)) if str(arr.dtype) != "bfloat16" else None
-    is_float = (dt is None or np.issubdtype(dt, np.floating))
-    return (is_float and getattr(arr, "ndim", 0) > seq_axis
-            and arr.shape[seq_axis] >= hi)
+def itemsize(dtype) -> int:
+    """Bytes per element of ``dtype``, bfloat16 included."""
+    return jnp.dtype(str(dtype)).itemsize
+
+
+def _is_float(arr) -> bool:
+    return jnp.issubdtype(jnp.dtype(str(arr.dtype)), jnp.floating)
+
+
+def _pageable(arr, token_axis, hi: int) -> bool:
+    return (token_axis is not None and _is_float(arr)
+            and getattr(arr, "ndim", 0) > token_axis
+            and arr.shape[token_axis] >= hi)
 
 
 @dataclasses.dataclass
@@ -79,8 +103,9 @@ class StagedBlock:
 
     @property
     def decoded_bytes(self) -> int:
-        """Size of the decoded (float32) tensors this block expands to."""
-        return sum(int(np.prod(c.shape)) * 4 for c in self.cs)
+        """Size of the decoded tensors this block expands to."""
+        return sum(int(np.prod(c.shape)) * itemsize(c.dtype)
+                   for c in self.cs)
 
 
 class KVPager:
@@ -88,17 +113,20 @@ class KVPager:
 
     One ``Codec`` drives both directions: its eb/mode compresses evicted
     blocks, its method/backend/t_high decode them back, and its plan cache
-    makes repeat page-ins phase-4 only.  Safe to share across threads: the
-    block table (``_blocks``), id counter, and ``stats`` are guarded by one
-    reentrant lock.
+    makes repeat page-ins phase-4 only.  ``layout`` (``{name:
+    token_axis}``, ``models/decode.cache_layout``) says where each tensor
+    holds its tokens; None marks a tensor that only :meth:`snapshot` takes,
+    whole.  Safe to share across threads: the block table (``_blocks``), id
+    counter, and ``stats`` are guarded by one reentrant lock.
     """
 
     def __init__(self, directory: str, *, codec: "Codec | None" = None,
-                 seq_axis: int = 2,
+                 seq_axis: int = 2, layout: "dict | None" = None,
                  plan_cache: "PlanCache | None" = None):
         self.dir = directory
         self.codec = codec if codec is not None else default_codec()
         self.seq_axis = seq_axis
+        self.layout = dict(layout or {})
         self.cache = (self.codec.plan_cache if plan_cache is None
                       else plan_cache)
         os.makedirs(directory, exist_ok=True)
@@ -107,14 +135,40 @@ class KVPager:
         self._next_id = 0
         self.stats = {"pages_out": 0, "pages_in": 0,
                       "bytes_raw": 0, "bytes_compressed": 0,
-                      "pages_lost": 0}
+                      "pages_lost": 0,
+                      "snapshots_out": 0, "snapshots_in": 0,
+                      "snapshot_bytes_raw": 0,
+                      "snapshot_bytes_compressed": 0}
 
     def _bump(self, key: str, n: int = 1):
         with self._lock:
             self.stats[key] += n
 
-    def _span(self, lo: int, hi: int):
-        return (slice(None),) * self.seq_axis + (slice(lo, hi),)
+    def token_axis(self, name: str) -> "int | None":
+        """The layout's token axis of tensor ``name`` (``seq_axis`` for a
+        name the layout does not list; None: never sliced)."""
+        return self.layout.get(name, self.seq_axis)
+
+    def span(self, name: str, lo: int, hi: int) -> tuple:
+        """Index of tokens [lo, hi) of tensor ``name``."""
+        return (slice(None),) * self.token_axis(name) + (slice(lo, hi),)
+
+    def pageable(self, cache: dict, hi: int, keys=None) -> list:
+        """The float tensors of ``cache`` (or of ``keys``) whose token axis
+        reaches ``hi``."""
+        return [k for k in (cache if keys is None else keys)
+                if _pageable(cache[k], self.token_axis(k), hi)]
+
+    def state_keys(self, cache: dict) -> list:
+        """The float tensors of ``cache`` without a token axis."""
+        return [k for k in cache
+                if self.token_axis(k) is None and _is_float(cache[k])]
+
+    def value_ranges(self, cache: dict, keys) -> dict:
+        """``{name: range}`` over each whole tensor (``Codec.value_range``:
+        None under an absolute bound), for ``offload(value_ranges=)`` of
+        every page of those tensors, so that they share one lattice."""
+        return {k: self.codec.value_range(cache[k]) for k in keys}
 
     def block_path(self, block_id: int) -> str:
         return os.path.join(self.dir, f"block_{block_id:06d}.szt")
@@ -125,7 +179,8 @@ class KVPager:
             return sorted(self._blocks)
 
     def block_meta(self, block_id: int) -> dict:
-        """{"path", "lo", "hi", "names"} of one offloaded block."""
+        """{"path", "lo", "hi", "names"} of one offloaded block (``lo`` and
+        ``hi`` None, and ``"snapshot": True``, for a snapshot)."""
         with self._lock:
             return dict(self._blocks[block_id])
 
@@ -154,44 +209,74 @@ class KVPager:
 
     # -- eviction -----------------------------------------------------------
 
-    def offload(self, cache: dict, lo: int, hi: int, keys=None):
+    def offload(self, cache: dict, lo: int, hi: int, keys=None,
+                value_ranges: "dict | None" = None):
         """Compress tokens [lo, hi) of each pageable tensor to one archive.
 
         Returns ``(cache, block_id)`` where ``cache`` has the paged region
         zeroed for every tensor that was written.  ``keys`` defaults to all
-        float tensors with a sequence axis covering the range.
+        float tensors with a token axis covering the range; a tensor
+        without a token axis is never paged.  ``value_ranges``
+        (``{name: range}``, from :meth:`value_ranges` before the first
+        page) fixes each tensor's relative bound; without it each page is
+        bounded by its own range.
         """
         if hi <= lo:
             raise ValueError(f"empty page range [{lo}, {hi})")
-        candidates = cache if keys is None else keys
-        keys = [k for k in candidates
-                if _pageable(k, cache[k], self.seq_axis, hi)]
+        keys = self.pageable(cache, hi, keys)
         if not keys:
             raise ValueError("no pageable cache tensors for range "
                              f"[{lo}, {hi})")
+        with trace.span("pager.offload", lo=lo, hi=hi):
+            block_id = self._write(
+                cache, keys, lambda k: cache[k][self.span(k, lo, hi)],
+                value_ranges or {}, {"lo": lo, "hi": hi},
+                ("pages_out", "bytes_raw", "bytes_compressed"))
+            for k in keys:
+                cache[k] = cache[k].at[self.span(k, lo, hi)].set(0)
+        return cache, block_id
+
+    def snapshot(self, cache: dict, keys=None) -> int:
+        """Compress whole tensors without a token axis into one archive.
+
+        ``keys`` defaults to every float tensor the layout gives no token
+        axis (:meth:`state_keys`).  The cache is left as it is.  Returns
+        the block id; a page-in of it restores each tensor whole.
+        """
+        keys = self.state_keys(cache) if keys is None else list(keys)
+        if not keys:
+            raise ValueError("no state tensors to snapshot")
+        with trace.span("pager.snapshot"):
+            return self._write(
+                cache, keys, lambda k: cache[k], {},
+                {"lo": None, "hi": None, "snapshot": True},
+                ("snapshots_out", "snapshot_bytes_raw",
+                 "snapshot_bytes_compressed"))
+
+    def _write(self, cache, keys, part, value_ranges, meta, counters) -> int:
+        """Compress ``part(k)`` of each tensor ``k`` (copied to the host)
+        into a new block's archive, each against ``value_ranges[k]`` where
+        given; register the block with ``meta`` and count it in the three
+        ``counters`` (blocks, raw bytes, stored bytes)."""
         with self._lock:
             block_id = self._next_id
             self._next_id += 1
-        span = self._span(lo, hi)
         path = self.block_path(block_id)
         raw_bytes = 0
         with ArchiveWriter(path) as w:
             for k in keys:
-                arr = cache[k]
-                block = np.asarray(arr[span], np.float32)
-                raw_bytes += block.size * np.dtype(
-                    str(arr.dtype) if str(arr.dtype) != "bfloat16"
-                    else np.float32).itemsize
-                w.add(k, self.codec.compress(block),
-                      orig_dtype=str(arr.dtype))
-                cache[k] = arr.at[span].set(0)
+                block = trace.to_host(part(k), np.float32)
+                raw_bytes += block.size * itemsize(cache[k].dtype)
+                w.add(k, self.codec.compress(
+                    block, value_range=value_ranges.get(k)),
+                    orig_dtype=str(cache[k].dtype))
+        n_out, n_raw, n_stored = counters
         with self._lock:
-            self._blocks[block_id] = {"path": path, "lo": lo, "hi": hi,
-                                      "names": keys}
-            self.stats["pages_out"] += 1
-            self.stats["bytes_raw"] += raw_bytes
-            self.stats["bytes_compressed"] += os.path.getsize(path)
-        return cache, block_id
+            self._blocks[block_id] = {"path": path, "names": keys, **meta}
+            self.stats[n_out] += 1
+            self.stats[n_raw] += raw_bytes
+            self.stats[n_stored] += os.path.getsize(path)
+        return block_id
 
     # -- page-in ------------------------------------------------------------
 
@@ -231,17 +316,18 @@ class KVPager:
         """
         meta = self._meta(block_id)
         try:
-            with Archive(meta["path"], codec=self.codec,
-                         plan_cache=self.cache) as ar:
-                missing = [k for k in meta["names"] if k not in ar]
-                if missing:
-                    raise F.StoreCorruptError(
-                        f"{meta['path']}: block is missing tensors "
-                        f"{missing}")
-                cs = [ar.read_chunk(n) for n in meta["names"]]
-                key = tuple(sorted(
-                    (n, ar.chunk(n).digest) for n in meta["names"]))
-            plans = [self.codec.plan_for(c) for c in cs]
+            with trace.span("pager.stage", block=block_id):
+                with Archive(meta["path"], codec=self.codec,
+                             plan_cache=self.cache) as ar:
+                    missing = [k for k in meta["names"] if k not in ar]
+                    if missing:
+                        raise F.StoreCorruptError(
+                            f"{meta['path']}: block is missing tensors "
+                            f"{missing}")
+                    cs = [ar.read_chunk(n) for n in meta["names"]]
+                    key = tuple(sorted(
+                        (n, ar.chunk(n).digest) for n in meta["names"]))
+                plans = [self.codec.plan_for(c) for c in cs]
         except (F.StoreError, hp.DecodeGuardError, OSError) as e:
             raise self._lose(block_id, meta["path"], e) from e
         with self._lock:
@@ -286,7 +372,10 @@ class KVPager:
                     if on_lost is None:
                         raise err from e
                     on_lost(s.block_id, err)
-        self._bump("pages_in", len(out))
+        snaps = sum(1 for s in staged
+                    if s.block_id in out and s.meta.get("snapshot"))
+        self._bump("snapshots_in", snaps)
+        self._bump("pages_in", len(out) - snaps)
         return out
 
     def fetch(self, block_id: int) -> dict:
@@ -326,11 +415,18 @@ class KVPager:
         propagates; the cache is untouched and the paged span stays zeroed,
         so a caller that catches the error keeps serving degraded.
         """
-        meta = self._meta(block_id)
-        span = self._span(meta["lo"], meta["hi"])
-        for k, block in self.fetch(block_id).items():
-            cache[k] = cache[k].at[span].set(
-                jnp.asarray(block, cache[k].dtype))
+        self._meta(block_id)
+        return self.install(cache, block_id, self.fetch(block_id))
+
+    def install(self, cache: dict, block_id: int, tensors: dict) -> dict:
+        """Put a fetched block's ``tensors`` into ``cache``: a page at its
+        token range, a snapshot's tensors whole."""
+        meta = self.block_meta(block_id)
+        with trace.span("pager.install", block=block_id):
+            for k, block in tensors.items():
+                block = jnp.asarray(block, cache[k].dtype)
+                cache[k] = (block if meta.get("snapshot") else cache[k].at[
+                    self.span(k, meta["lo"], meta["hi"])].set(block))
         return cache
 
     def adopt_block(self, block_id: int, meta: dict):
@@ -363,9 +459,11 @@ class KVPager:
 
     @property
     def ratio(self) -> float:
-        """Achieved compression ratio; ``0.0`` until something has been
-        offloaded (no more ``bytes_raw / 1`` nonsense on an idle pager)."""
+        """Achieved compression ratio of pages and snapshots together;
+        ``0.0`` until something has been offloaded."""
         with self._lock:
-            if self.stats["bytes_compressed"] == 0:
+            st = self.stats
+            stored = st["bytes_compressed"] + st["snapshot_bytes_compressed"]
+            if stored == 0:
                 return 0.0
-            return self.stats["bytes_raw"] / self.stats["bytes_compressed"]
+            return (st["bytes_raw"] + st["snapshot_bytes_raw"]) / stored

@@ -75,7 +75,8 @@ class TestDecodeConsistency:
 
     @pytest.mark.parametrize("arch", ["qwen3-0.6b", "h2o-danube-1.8b",
                                       "rwkv6-3b", "zamba2-7b",
-                                      "deepseek-v3-671b"])
+                                      "deepseek-v3-671b",
+                                      "kimi-linear-48b-a3b"])
     def test_decode_matches_forward(self, arch):
         cfg = configs.get_config(arch).reduced(n_layers=2)
         params = T.init_model(jax.random.PRNGKey(0), cfg)
@@ -96,12 +97,12 @@ class TestDecodeConsistency:
 
 class TestConfigRegistry:
     def test_all_archs_present(self):
-        assert len(configs.REGISTRY) == 10
+        assert len(configs.REGISTRY) == 11
 
     def test_cell_count(self):
-        # 10 archs x 4 shapes - 7 long_500k skips = 33
-        assert len(configs.cells()) == 33
-        assert len(configs.cells(include_skipped=True)) == 40
+        # 11 archs x 4 shapes - 8 long_500k skips = 36
+        assert len(configs.cells()) == 36
+        assert len(configs.cells(include_skipped=True)) == 44
 
     def test_exact_assigned_dims(self):
         c = configs.get_config("deepseek-v3-671b")
@@ -117,7 +118,8 @@ class TestConfigRegistry:
     def test_param_counts_close_to_nameplate(self):
         from repro.models.config import count_params
         expect = {"qwen3-0.6b": 0.6e9, "deepseek-v3-671b": 671e9,
-                  "qwen2-vl-72b": 72e9, "qwen2.5-3b": 3.1e9}
+                  "qwen2-vl-72b": 72e9, "qwen2.5-3b": 3.1e9,
+                  "kimi-linear-48b-a3b": 48e9}
         for name, n in expect.items():
             got = count_params(configs.get_config(name))
             assert abs(got - n) / n < 0.15, (name, got)

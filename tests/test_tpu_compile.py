@@ -165,6 +165,26 @@ def test_decode_tiles_fused_nd_derived(compiled):
              S((1,), jnp.float32))
 
 
+@pytest.mark.parametrize("shape,rows_per_tile,lanes", [
+    ((7, 4, 1024, 576), 16, 768),        # a 1,024-token latent page
+    ((20, 4, 32, 128, 128), 64, 640),    # the KDA states, rank 5
+    ((20, 4, 12288, 3), 4096, 1024),     # the KDA convolution windows
+])
+def test_decode_tiles_fused_nd_serving_cache(compiled, shape, rows_per_tile,
+                                             lanes):
+    """Kimi-Linear-48B-A3B's cache tensors at the geometry
+    ``pipeline.tile_geometry`` derives for seeded normal values (rel eb
+    1e-3): the benchmark's ``kimi-linear-48b.preempt-resume`` decodes."""
+    S, compile_ = compiled
+    compile_(functools.partial(F.decode_tiles_fused_nd, max_len=MAX_LEN,
+                               lut_size=1 << MAX_LEN,
+                               rows_per_tile=rows_per_tile, shape=shape,
+                               ss_max=lanes, radius=RADIUS),
+             _tile_inputs(S, 16, lanes, 2),
+             S((LUT_ROWS, C.LANES), jnp.int32), _side(S),
+             S((1,), jnp.float32))
+
+
 def test_decode_tiles_fused_1d_derived(compiled):
     """The largest flat tile the derivation asks for at 128 symbols a
     window: 131072 symbols over 1024 lanes."""

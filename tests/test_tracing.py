@@ -20,6 +20,7 @@ import pytest
 
 from repro.checkpoint.manager import ARCHIVE_NAME, CheckpointManager
 from repro.core import Codec, CodecConfig
+from repro.models import kvcache
 from repro.core.huffman.pipeline import T_HIGH_DEFAULT
 from repro.core.sz.lorenzo import DEFAULT_RADIUS
 from repro.runtime import trace
@@ -39,6 +40,23 @@ def _tree():
     return {"w": jax.random.normal(key, (64, 96), jnp.float32).cumsum(0),
             "v": jax.random.normal(key, (48, 64), jnp.float32),
             "norm": jnp.ones((32,), jnp.float32)}
+
+
+def _kv_cache():
+    """A small hybrid decode cache: a paged latent, a state snapshot."""
+    key = jax.random.PRNGKey(1)
+    return {"latent": jax.random.normal(key, (2, 1, 64, 8)).cumsum(2),
+            "kda_state": jax.random.normal(key, (3, 1, 2, 4, 4))}
+
+
+KV_LAYOUT = {"latent": 2, "kda_state": None}
+
+
+def _preempt_resume(directory):
+    pager = kvcache.KVPager(directory, codec=Codec(CFG), layout=KV_LAYOUT)
+    handle = kvcache.preempt(_kv_cache(), pager, page_tokens=32)
+    fresh = kvcache.KVPager(directory, codec=Codec(CFG), layout=KV_LAYOUT)
+    return kvcache.resume(fresh, handle)
 
 
 def _read_spans(directory):
@@ -91,6 +109,7 @@ def traced(tmp_path_factory):
     CheckpointManager(ckpt, codec=Codec(CFG)).restore()
     with Archive(arch, codec=Codec(CFG)) as ar:
         ar.read_all(group_chunks=1)
+    _preempt_resume(os.path.join(tmp, "kv0"))
     with jax.profiler.trace(os.path.join(tmp, "trace")):
         CheckpointManager(ckpt, codec=Codec(CFG), compress_min_size=MIN_SIZE
                           ).save(2, tree)
@@ -100,6 +119,7 @@ def traced(tmp_path_factory):
             # One chunk per group: more than one group, so every group is
             # staged on the prefetch thread.
             jax.block_until_ready(ar.read_all(group_chunks=1))
+        jax.block_until_ready(_preempt_resume(os.path.join(tmp, "kv1")))
     return _read_spans(os.path.join(tmp, "trace"))
 
 
@@ -126,26 +146,36 @@ PARENTS = {
     "repro.ckpt.save": {None}, "repro.ckpt.restore": {None},
     "repro.archive.read_all": {None, "repro.ckpt.restore"},
     "repro.ckpt.snapshot": {"repro.ckpt.save"},
-    "repro.codec.compress": {"repro.ckpt.save"},
+    "repro.codec.compress": {"repro.ckpt.save", "repro.pager.offload",
+                             "repro.pager.snapshot"},
     "repro.compress.stats": {"repro.codec.compress"},
+    "repro.codec.value_range": {"repro.pager.preempt"},
     "repro.compress.outliers": {"repro.codec.compress"},
     "repro.compress.codebook": {"repro.codec.compress"},
     "repro.compress.pack": {"repro.codec.compress"},
-    "repro.archive.add": {"repro.ckpt.save"},
+    "repro.archive.add": {"repro.ckpt.save", "repro.pager.offload",
+                          "repro.pager.snapshot"},
     "repro.archive.fetch": {"repro.archive.add"},
     "repro.ckpt.save_raw": {"repro.ckpt.save"},
     "repro.ckpt.publish": {"repro.ckpt.save"},
     "repro.ckpt.manifest": {"repro.ckpt.restore"},
-    "repro.archive.open": {"repro.ckpt.restore", None},
-    "repro.archive.stage": {"repro.archive.read_all", None},
+    "repro.archive.open": {"repro.ckpt.restore", "repro.pager.stage", None},
+    "repro.archive.stage": {"repro.archive.read_all", "repro.pager.stage",
+                            None},
     "repro.archive.wait": {"repro.archive.read_all"},
     "repro.archive.cast": {"repro.archive.read_all"},
-    "repro.plan.build": {"repro.archive.read_all"},
+    "repro.plan.build": {"repro.archive.read_all", "repro.pager.stage"},
     "repro.plan.count": {"repro.plan.build"},
     "repro.plan.offsets": {"repro.plan.build"},
     "repro.plan.classify": {"repro.plan.build"},
-    "repro.decode.dispatch": {"repro.archive.read_all"},
+    "repro.decode.dispatch": {"repro.archive.read_all",
+                              "repro.pager.resume"},
     "repro.ckpt.load_raw": {"repro.ckpt.restore"},
+    "repro.pager.preempt": {None}, "repro.pager.resume": {None},
+    "repro.pager.offload": {"repro.pager.preempt"},
+    "repro.pager.snapshot": {"repro.pager.preempt"},
+    "repro.pager.stage": {"repro.pager.resume"},
+    "repro.pager.install": {"repro.pager.resume"},
 }
 
 
