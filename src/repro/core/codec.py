@@ -218,8 +218,9 @@ class Codec:
         backend (and ``reset_stats`` zeroes them for all of them); the
         plan-cache counters are per-codec unless a cache was injected.
         The encode backend's write-path counters (``encode_dispatches``,
-        ``encode_fallbacks``, ``encoder_plan_builds``) merge in under their
-        own keys -- disjoint from the decode counters by construction.
+        ``encode_fallbacks``, ``encoder_plan_builds``, ``encode_pack_steps``,
+        ``encode_pack_symbol_rows``) merge in under their own keys --
+        disjoint from the decode counters by construction.
         ``h2d_bytes``, ``d2h_bytes``, ``compiles`` and ``compile_ms`` come
         from ``runtime/trace.py`` (docs/api.md, "Tracing").
         """

@@ -227,7 +227,7 @@ def _encode_gather_padded(
         # the codeword occupies the 64-bit window
         # ``code << (64 - o - length)`` whose high word lands in unit ``u``
         # and low word in ``u + 1`` -- identical arithmetic to
-        # kernels/huffman_encode._pack_kernel.
+        # kernels/huffman_encode._split.
         p = starts[kc] - base_u
         u = p >> 5
         o = p & 31

@@ -562,7 +562,12 @@ class EncodeBackend:
     ``pack_fn``      (symbols, enc_code, enc_len, total_bits, sps, min_len)
                      -> ``EncodedStream``
 
-    Every bit-pack launch is counted in ``stats["encode_dispatches"]``;
+    ``geometry_fn``  (n_symbols, total_bits, sps, min_len) -> the bit-pack
+                     kernel's ``(steps, symbol_rows)``, for backends that
+                     run one (host arithmetic, no device read)
+
+    Every bit-pack launch is counted in ``stats["encode_dispatches"]``,
+    and a kernel's grid in ``encode_pack_steps`` / ``encode_pack_symbol_rows``;
     compress requests a device backend cannot serve (non-float32 inputs)
     fall back to the host path, counted in ``stats["encode_fallbacks"]``,
     never wrong.
@@ -573,10 +578,13 @@ class EncodeBackend:
     quantize_fn: Callable
     hist_fn: Callable
     pack_fn: Callable
+    geometry_fn: "Callable | None" = None
     stats: dict = dataclasses.field(
         default_factory=lambda: {"encode_dispatches": 0,
                                  "encode_fallbacks": 0,
-                                 "encoder_plan_builds": 0})
+                                 "encoder_plan_builds": 0,
+                                 "encode_pack_steps": 0,
+                                 "encode_pack_symbol_rows": 0})
     _stats_lock: threading.Lock = dataclasses.field(
         default_factory=threading.Lock, repr=False, compare=False)
 
@@ -592,6 +600,12 @@ class EncodeBackend:
 
     def pack(self, symbols, enc_code, enc_len, total_bits, sps, min_len):
         self.bump("encode_dispatches")
+        if self.geometry_fn is not None:
+            steps, rows = self.geometry_fn(int(np.shape(symbols)[0]),
+                                           total_bits, sps, min_len)
+            with self._stats_lock:
+                self.stats["encode_pack_steps"] += steps
+                self.stats["encode_pack_symbol_rows"] += rows
         return self.pack_fn(symbols, enc_code, enc_len, total_bits, sps,
                             min_len)
 
@@ -709,7 +723,8 @@ def _make_pallas_encode_backend() -> EncodeBackend:
                                   sps, min_len=min_len)
 
     return EncodeBackend(name="pallas", device=True, quantize_fn=quantize,
-                         hist_fn=ops.histogram, pack_fn=pack)
+                         hist_fn=ops.histogram, pack_fn=pack,
+                         geometry_fn=ops.encode_bitpack_geometry)
 
 
 register_encode_backend("ref", _make_ref_encode_backend)

@@ -322,23 +322,27 @@ def _encode_bitpack_padded(symbols, enc_code, enc_len, n_units_padded: int,
     total_bits = (starts[-1] + lens[-1]).astype(jnp.int32)
     n = symbols.shape[0]
 
-    # --- output row -> symbol window (mirrors the decode kernels' prep) --
-    n_rows = -(-n_units_padded // C.LANES)
-    row_base = jnp.arange(n_rows, dtype=jnp.int32) * _enc.ROW_BITS
-    # First symbol that can touch each row: the one crossing in from the
-    # left (or the first starting in it); its 8-row-aligned symbol row.
-    s0 = jnp.maximum(jnp.searchsorted(starts, row_base, side="right") - 1, 0)
-    row0 = (s0 // (C.SUBLANES * C.LANES) * C.SUBLANES).astype(jnp.int32)
-    sym_rows = C.round_up(-(-n // C.LANES), C.SUBLANES) + _enc.window_rows(
-        min_len)
+    # --- grid step -> symbol rows to walk (mirrors the decode kernels' prep)
+    g = _enc.pack_geometry(n, n_units_padded, min_len)
+    step_base = jnp.arange(g.steps + 1, dtype=jnp.int32) * (
+        g.rows * _enc.ROW_BITS)
+    # The codeword crossing into each step (or the first starting in it);
+    # a step walks from its symbol row to the row of the next step's one.
+    s0 = jnp.maximum(jnp.searchsorted(starts, step_base, side="right") - 1,
+                     0)
+    srow = (s0 // C.LANES).astype(jnp.int32)
+    row0 = srow[:-1] // C.SUBLANES * C.SUBLANES
+    bounds = jnp.stack([row0, srow[:-1] - row0, srow[1:] - srow[:-1] + 1],
+                       axis=-1)[:, None, :]
+    sym_rows = C.round_up(-(-n // C.LANES), C.SUBLANES) + g.window
     pad = sym_rows * C.LANES - n
 
     def rows(a):
         return jnp.concatenate([a, jnp.zeros((pad,), a.dtype)]).reshape(
             sym_rows, C.LANES)
 
-    units = _enc.pack_rows(row0[:, None, None], rows(codes), rows(lens),
-                           rows(starts), min_len)
+    units = _enc.pack_rows(bounds, rows(codes), rows(lens), rows(starts),
+                           rows=g.rows, window=g.window)
     units = units.reshape(-1)[:n_units_padded]
 
     gaps, counts, seq_counts = he.stream_metadata(
@@ -353,7 +357,7 @@ def _encode_bitpack_padded(symbols, enc_code, enc_len, n_units_padded: int,
 def encode_bitpack(symbols, enc_code, enc_len, total_bits: int,
                    subseqs_per_seq: int, min_len: int = 1
                    ) -> he.EncodedStream:
-    """Kernel-backed Huffman encode: per-row prefix-sum bit placement.
+    """Kernel-backed Huffman encode: codewords placed a symbol row at a time.
 
     ``total_bits`` is the exact payload size (the ``EncoderPlan`` derives
     it from the histogram, so the symbol array never round-trips to host);
@@ -367,6 +371,19 @@ def encode_bitpack(symbols, enc_code, enc_len, total_bits: int,
     return _encode_bitpack_padded(symbols, jnp.asarray(enc_code),
                                   jnp.asarray(enc_len), n_units_padded,
                                   subseqs_per_seq, min_len)
+
+
+def encode_bitpack_geometry(n_symbols: int, total_bits: int,
+                            subseqs_per_seq: int, min_len: int = 1):
+    """``(steps, symbol_rows)`` of the bit-pack kernel :func:`encode_bitpack`
+    runs for these sizes (``huffman_encode.pack_geometry``); ``(0, 0)`` for
+    an empty stream, which launches none."""
+    if n_symbols == 0:
+        return 0, 0
+    g = _enc.pack_geometry(n_symbols,
+                           he.units_for_bits(total_bits, subseqs_per_seq),
+                           min_len)
+    return g.steps, g.symbol_rows
 
 
 # ---------------------------------------------------------------------------

@@ -218,14 +218,18 @@ def test_histogram(compiled):
              S((1 << 20,), jnp.uint16))
 
 
-@pytest.mark.parametrize("min_len", [1, 3])
-def test_pack_rows(compiled, min_len):
+@pytest.mark.parametrize("min_len,n_syms,n_units", [
+    pytest.param(1, 1 << 20, 1 << 16, id="1"),      # 2 bits a code (nyx)
+    pytest.param(3, 1 << 20, 5 << 16, id="3"),      # 10 bits a code (danube)
+    pytest.param(8, 1 << 18, 3 << 15, id="8"),      # no code under 8 bits
+    pytest.param(3, 1000, 384, id="one-partial-step"),
+])
+def test_pack_rows(compiled, min_len, n_syms, n_units):
     S, compile_ = compiled
-    n_rows = 64
-    sym_rows = C.round_up(n_rows, C.SUBLANES) + E.window_rows(min_len)
-    sym = (sym_rows, C.LANES)
-    compile_(functools.partial(E.pack_rows, min_len=min_len),
-             S((n_rows, 1, 1), jnp.int32), S(sym, jnp.uint32),
+    g = E.pack_geometry(n_syms, n_units, min_len)
+    sym = (C.round_up(-(-n_syms // C.LANES), C.SUBLANES) + g.window, C.LANES)
+    compile_(functools.partial(E.pack_rows, rows=g.rows, window=g.window),
+             S((g.steps, 1, 3), jnp.int32), S(sym, jnp.uint32),
              S(sym, jnp.int32), S(sym, jnp.int32))
 
 
